@@ -37,6 +37,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from benchmarks.common import emit, emit_json
 from repro.autotune import (
@@ -48,7 +49,6 @@ from repro.autotune import (
 )
 from repro.autotune import measure
 from repro.autotune.space import DEFAULT_PLAN
-from repro.compat import shard_map
 from repro.configs import ServeConfig, get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.core import collective_matmul as cm
@@ -179,7 +179,7 @@ def run(n_dev: int = 8, quick: bool = True, iters: int = 3):
     if quick:
         iters = min(iters, 2)
     mesh = make_mesh((n_dev,), ("model",))
-    serve_mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    serve_mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     cache = global_cache()
 
     builders = {

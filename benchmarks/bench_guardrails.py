@@ -23,18 +23,19 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from benchmarks.common import emit, emit_json, time_fn
-from repro.compat import shard_map
 from repro.configs import ServeConfig, get_smoke_config
 from repro.core import faults, queues
 from repro.core.topology import ring
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, split_tree
 from repro.serve.sharded_cache import RingShardedBackend
 
 
 def bench_streams(results: dict, n: int, k: int, iters: int):
-    mesh = jax.make_mesh((n,), ("pe",))
+    mesh = make_mesh((n,), ("pe",))
     topo = ring("pe", n)
     xs = jax.random.normal(jax.random.PRNGKey(0), (n, k), jnp.float32)
 
@@ -70,8 +71,8 @@ def bench_serve_step(results: dict, iters: int):
     scfg = ServeConfig(max_batch=4, max_seq_len=64, temperature=0.0)
     model = build_model(cfg)
     params, _ = split_tree(model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 4), ("data", "model"),
-                         devices=jax.devices()[:4])
+    mesh = make_mesh((1, 4), ("data", "model"),
+                     devices=jax.devices()[:4])
     tokens = np.ones((scfg.max_batch, 1), np.int32)
     active = np.ones(scfg.max_batch, bool)
 

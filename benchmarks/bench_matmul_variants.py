@@ -27,9 +27,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from benchmarks.common import emit, emit_json, hlo_counts, time_fn
-from repro.compat import shard_map
 from repro.core import energy
 from repro.core.collective_matmul import cannon_matmul, ring_ag_matmul
 from repro.core.topology import Topology, ring, snake_ring, torus_shift

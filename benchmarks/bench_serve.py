@@ -31,6 +31,7 @@ import jax
 
 from benchmarks.common import emit, emit_json
 from repro.configs import ServeConfig, get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, split_tree
 from repro.serve.engine import ServeEngine
 from repro.serve.sharded_cache import DecodeBackend, RingShardedBackend
@@ -86,7 +87,7 @@ def run(n_dev: int = 8):
     model = build_model(cfg)
     params, _ = split_tree(model.init(jax.random.PRNGKey(0)))
     scfg = ServeConfig(max_batch=8, max_seq_len=64, temperature=0.0)
-    mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
 
     results: dict = {}
     backends = [("dense", None, scfg)]

@@ -19,6 +19,7 @@ import numpy as np
 import jax
 
 from repro.configs import ServeConfig, get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, split_tree
 from repro.serve.engine import ServeEngine
 from repro.serve.sharded_cache import RingShardedBackend
@@ -45,10 +46,7 @@ def main():
                        prefill_chunk=args.prefill_chunk)
     backend = None
     if args.ring:
-        from jax.sharding import Mesh
-        n = jax.device_count()
-        mesh = Mesh(np.asarray(jax.devices()).reshape(1, n),
-                    ("data", "model"))
+        mesh = make_mesh((1, jax.device_count()), ("data", "model"))
         backend = RingShardedBackend(cfg, scfg, params, mesh, mode=args.mode)
     engine = ServeEngine(cfg, scfg, params, backend=backend)
 

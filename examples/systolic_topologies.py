@@ -20,8 +20,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core.collective_matmul import ring_ag_matmul
 from repro.core.fft import pipelined_fft
 from repro.core.halo import conv2d_ref, conv2d_systolic

@@ -31,8 +31,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax.lax import optimization_barrier
 
-from repro.compat import optimization_barrier
 from repro.core import queues
 from repro.core import topology as topo_lib
 from repro.core.topology import Topology, ring

@@ -19,8 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import queues
 from repro.core.topology import Topology, ring
 

@@ -22,8 +22,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.lax import optimization_barrier
 
-from repro.compat import optimization_barrier, shard_map
 from repro.core.topology import chains
 
 

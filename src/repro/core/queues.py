@@ -52,8 +52,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.lax import optimization_barrier
 
-from repro.compat import optimization_barrier
 from repro.core import faults
 from repro.core.topology import GridSchedule, Topology
 from repro.obs import linkstats

@@ -7,45 +7,51 @@ stationary VMEM block; batches of FFTs stream through the grid. Complex
 values travel as separate real/imag planes (VPU-friendly; TPUs have no
 complex MXU type). One kernel call = one stage; the 4-stage pipeline is
 driven by ops.py (or distributed across devices by core.fft.pipelined_fft).
+
+The radix-4 butterflies of a stage mix lanes that lie ``n / 4**(stage+1)``
+apart, which no lane-splitting reshape the TPU's compiler accepts can
+express. So the butterflies are one stationary [n, n] matrix per stage
+(entries 0, +-1, +-1j, as real and imaginary planes) applied on the MXU at
+full fp32 precision; the products by 0 and +-1 are exact.
 """
 from __future__ import annotations
 
-import functools
+import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _stage_kernel(xr_ref, xi_ref, twr_ref, twi_ref, or_ref, oi_ref, *,
-                  stage: int, n: int):
+def butterfly_matrix(n: int, stage: int) -> tuple[np.ndarray, np.ndarray]:
+    """(real, imag) planes of the [n, n] matrix B with ``y @ B`` = the
+    radix-4 butterflies of ``stage``: within each group of L = 4**(stage+1)
+    lanes, out[k*q + j] = sum_k' F4[k, k'] * y[k'*q + j], q = L / 4."""
+    L = 4 ** (stage + 1)
+    q = L // 4
+    f4 = np.exp(-2j * np.pi * np.outer(np.arange(4), np.arange(4)) / 4)
+    f4 = np.round(f4.real) + 1j * np.round(f4.imag)
+    b = np.kron(np.eye(n // L), np.kron(f4.T, np.eye(q)))
+    return b.real.astype(np.float32), b.imag.astype(np.float32)
+
+
+def _stage_kernel(xr_ref, xi_ref, twr_ref, twi_ref, br_ref, bi_ref,
+                  or_ref, oi_ref):
     xr = xr_ref[...].astype(jnp.float32)                     # [bb, n]
     xi = xi_ref[...].astype(jnp.float32)
     twr = twr_ref[...].astype(jnp.float32)                   # [1, n]
     twi = twi_ref[...].astype(jnp.float32)
-    # twiddle multiply (complex): x * tw
+    # twiddle multiply (complex): y = x * tw
     yr = xr * twr - xi * twi
     yi = xr * twi + xi * twr
-    bb = yr.shape[0]
-    L = 4 ** (stage + 1)
-    q = L // 4
-    shape = (bb, n // L, 4, q)
-    ar, ai = yr.reshape(shape), yi.reshape(shape)
-    a_r, b_r, c_r, d_r = ar[:, :, 0], ar[:, :, 1], ar[:, :, 2], ar[:, :, 3]
-    a_i, b_i, c_i, d_i = ai[:, :, 0], ai[:, :, 1], ai[:, :, 2], ai[:, :, 3]
-    # radix-4 butterfly: t3 = (b - d) * (-1j)
-    t0r, t0i = a_r + c_r, a_i + c_i
-    t1r, t1i = a_r - c_r, a_i - c_i
-    t2r, t2i = b_r + d_r, b_i + d_i
-    t3r, t3i = b_i - d_i, -(b_r - d_r)
-    o0r, o0i = t0r + t2r, t0i + t2i
-    o1r, o1i = t1r + t3r, t1i + t3i
-    o2r, o2i = t0r - t2r, t0i - t2i
-    o3r, o3i = t1r - t3r, t1i - t3i
-    outr = jnp.stack([o0r, o1r, o2r, o3r], axis=2).reshape(bb, n)
-    outi = jnp.stack([o0i, o1i, o2i, o3i], axis=2).reshape(bb, n)
-    or_ref[...] = outr.astype(or_ref.dtype)
-    oi_ref[...] = outi.astype(oi_ref.dtype)
+    br, bi = br_ref[...], bi_ref[...]                        # [n, n]
+
+    def mm(u, v):
+        return jnp.dot(u, v, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    # radix-4 butterflies: (yr + i yi) @ (br + i bi)
+    or_ref[...] = (mm(yr, br) - mm(yi, bi)).astype(or_ref.dtype)
+    oi_ref[...] = (mm(yr, bi) + mm(yi, br)).astype(oi_ref.dtype)
 
 
 def fft_stage(xr: jax.Array, xi: jax.Array, twr: jax.Array, twi: jax.Array,
@@ -54,22 +60,23 @@ def fft_stage(xr: jax.Array, xi: jax.Array, twr: jax.Array, twi: jax.Array,
     b, n = xr.shape
     bb = min(bb, b)
     assert b % bb == 0
-    body = functools.partial(_stage_kernel, stage=stage, n=n)
+    br, bi = butterfly_matrix(n, stage)
+    rows = pl.BlockSpec((bb, n), lambda i: (i, 0))
     call = pl.pallas_call(
-        body,
+        _stage_kernel,
         grid=(b // bb,),
         in_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
+            rows,
+            rows,
             pl.BlockSpec((1, n), lambda i: (0, 0)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
+            pl.BlockSpec((n, n), lambda i: (0, 0)),
+            pl.BlockSpec((n, n), lambda i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-        ],
+        out_specs=[rows, rows],
         out_shape=[jax.ShapeDtypeStruct((b, n), xr.dtype),
                    jax.ShapeDtypeStruct((b, n), xi.dtype)],
         interpret=interpret,
     )
-    return call(xr, xi, twr[None], twi[None])
+    return call(xr, xi, twr[None], twi[None], jnp.asarray(br),
+                jnp.asarray(bi))

@@ -19,9 +19,12 @@ Two entry points share one kernel body:
     per-row decode positions), and GQA is native: the query head groups
     ride a separate grid dimension over one unexpanded KV head — no
     ``jnp.repeat`` materialization.
-  * ``flash_attention`` — the self-contained form (zero state in, the
-    normalized output written on the last KV block), kept as the
-    single-launch local kernel.
+  * ``normalize=True`` — the self-contained form (zero state in, the
+    normalized output written on the last KV block).
+
+The offsets and the per-row bounds are scalar-prefetched into SMEM; the
+kernel rebuilds the positions of its block from them with ``iota``, so no
+operand needs a block narrower than the TPU's (8, 128) tile.
 """
 from __future__ import annotations
 
@@ -32,37 +35,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
 _NEG_INF = -1e30
 
 
-def largest_dividing_block(dim: int, preferred: int) -> int:
-    """Largest block size <= preferred that divides dim exactly (>= 1).
+def sublane_block(dim: int, preferred: int) -> int:
+    """Largest divisor of dim <= preferred that is a multiple of 8, or dim
+    itself when there is none: the second-minor block dimension on TPU must
+    be one or the other. Non-tiling shapes (e.g. S=192 under the default
+    128) shrink instead of crashing; the wrappers warn once per shape."""
+    for b in range(min(preferred, dim), 7, -1):
+        if dim % b == 0 and b % 8 == 0:
+            return b
+    return dim
 
-    Non-tiling shapes (e.g. S=192 under the default 128 block) shrink to
-    the largest divisor instead of crashing the wrapper's divisibility
-    assert; callers warn once when the shrink is large."""
-    b = max(1, min(preferred, dim))
-    while dim % b:
-        b -= 1
-    return b
 
-
-def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, klen_ref,
-                  m_ref, l_ref, acc_ref,
-                  mo_ref, lo_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *,
-                  scale: float, n_kv: int, causal: bool, window: int,
-                  normalize: bool):
+def _flash_kernel(off_ref, klen_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
+                  acc_ref, mo_ref, lo_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                  scale: float, bq: int, bkv: int, n_kv: int, causal: bool,
+                  window: int, normalize: bool):
     """Grid point (b', g, iq, ik): fold KV block ik into q block (b',g,iq).
 
     b' indexes batch x KV-head (the unexpanded GQA layout), g the query
-    head group sharing that KV head. Positions arrive as data (they are
-    traced device/shard offsets inside shard_map), so the same compiled
-    kernel serves every ring hop.
+    head group sharing that KV head. The q/k offsets and the row bounds
+    arrive as data (they are traced device/shard offsets inside
+    shard_map), so the same compiled kernel serves every ring hop.
     """
-    ik = pl.program_id(3)
+    row, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
     def _load_state():
@@ -73,15 +71,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, klen_ref,
     q = q_ref[0, 0].astype(jnp.float32)                      # [bq, d]
     k = k_ref[0].astype(jnp.float32)                         # [bkv, d]
     v = v_ref[0].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    q_pos = qpos_ref[:, 0]                                   # [bq] int32
-    k_pos = kpos_ref[:, 0]                                   # [bkv] int32
-    mask = k_pos[None, :] < klen_ref[0, 0]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    q_pos = (off_ref[0] + iq * bq
+             + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0))
+    k_pos = (off_ref[1] + ik * bkv
+             + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1))
+    mask = k_pos < klen_ref[row]
     if causal:
-        mask = jnp.logical_and(mask, k_pos[None, :] <= q_pos[:, None])
+        mask = jnp.logical_and(mask, k_pos <= q_pos)
     if window:
-        mask = jnp.logical_and(mask,
-                               q_pos[:, None] - k_pos[None, :] < window)
+        mask = jnp.logical_and(mask, q_pos - k_pos < window)
     s = jnp.where(mask, s, _NEG_INF)
 
     m_prev = m_scr[...]
@@ -104,7 +104,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, klen_ref,
             o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
 
 
-def flash_carry(q, k, v, m, l, acc, q_pos, k_pos, klen, *,
+def flash_carry(q, k, v, m, l, acc, q_offset, k_offset, klen, *,
                 causal: bool = True, window: int = 0, bq: int = 128,
                 bkv: int = 128, normalize: bool = False,
                 interpret: bool = False, out_dtype=None):
@@ -115,10 +115,11 @@ def flash_carry(q, k, v, m, l, acc, q_pos, k_pos, klen, *,
     k, v:       [B', T, D] — one unexpanded KV block.
     m, l:       [B', G, Sq, 1] fp32 running max / normalizer.
     acc:        [B', G, Sq, D] fp32 accumulator.
-    q_pos:      [Sq, 1] int32 global query positions (may be traced).
-    k_pos:      [T, 1] int32 global key positions.
-    klen:       [B', 1] int32 per-row valid-key bound: key j participates
-                iff k_pos[j] < klen[b'] (padded tails, decode positions).
+    q_offset:   int32 scalar, global position of query 0 (may be traced);
+                query i sits at q_offset + i.
+    k_offset:   int32 scalar, global position of key 0.
+    klen:       [B'] int32 per-row valid-key bound: key j participates
+                iff k_offset + j < klen[b'] (padded tails, decode positions).
 
     Returns (m, l, acc) updated; with ``normalize=True`` the third output
     is instead the normalized attention output acc/l cast to ``out_dtype``
@@ -126,71 +127,51 @@ def flash_carry(q, k, v, m, l, acc, q_pos, k_pos, klen, *,
     """
     bh, g, sq, d = q.shape
     t = k.shape[1]
-    bq = largest_dividing_block(sq, bq)
-    bkv = largest_dividing_block(t, bkv)
+    bq = sublane_block(sq, bq)
+    bkv = sublane_block(t, bkv)
     scale = 1.0 / (d ** 0.5)
     n_kv = t // bkv
     out_dtype = (out_dtype or q.dtype) if normalize else jnp.float32
     body = functools.partial(
-        _flash_kernel, scale=scale, n_kv=n_kv, causal=causal,
-        window=window, normalize=normalize)
-    params = pallas_compiler_params(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                            "arbitrary"))
-    call = pl.pallas_call(
-        body,
+        _flash_kernel, scale=scale, bq=bq, bkv=bkv, n_kv=n_kv,
+        causal=causal, window=window, normalize=normalize)
+    state = lambda b, h, i, j, *_: (b, h, i, 0)              # noqa: E731
+    kv = lambda b, h, i, j, *_: (b, j, 0)                    # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(bh, g, sq // bq, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, h, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, h, i, j: (b, j, 0)),
-            pl.BlockSpec((bq, 1), lambda b, h, i, j: (i, 0)),
-            pl.BlockSpec((bkv, 1), lambda b, h, i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, i, j: (b, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, d), state),
+            pl.BlockSpec((1, bkv, d), kv),
+            pl.BlockSpec((1, bkv, d), kv),
+            pl.BlockSpec((1, 1, bq, 1), state),
+            pl.BlockSpec((1, 1, bq, 1), state),
+            pl.BlockSpec((1, 1, bq, d), state),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, g, sq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bh, g, sq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bh, g, sq, d), out_dtype),
+            pl.BlockSpec((1, 1, bq, 1), state),
+            pl.BlockSpec((1, 1, bq, 1), state),
+            pl.BlockSpec((1, 1, bq, d), state),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
-        **({"compiler_params": params} if params else {}),
     )
-    return tuple(call(q, k, v, q_pos.astype(jnp.int32),
-                      k_pos.astype(jnp.int32), klen.astype(jnp.int32),
-                      m, l, acc))
-
-
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, bq: int = 128, bkv: int = 128,
-                    interpret: bool = False) -> jax.Array:
-    """q,k,v: [BH, S, D] (heads folded into batch). Returns [BH, S, D].
-
-    The self-contained form of :func:`flash_carry`: zero initial state,
-    one launch, normalized output."""
-    bh, s, d = q.shape
-    skv = k.shape[1]
-    m0 = jnp.full((bh, 1, s, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bh, 1, s, 1), jnp.float32)
-    acc0 = jnp.zeros((bh, 1, s, d), jnp.float32)
-    q_pos = jnp.arange(s, dtype=jnp.int32)[:, None]
-    k_pos = jnp.arange(skv, dtype=jnp.int32)[:, None]
-    klen = jnp.full((bh, 1), skv, jnp.int32)
-    _, _, out = flash_carry(
-        q[:, None], k, v, m0, l0, acc0, q_pos, k_pos, klen,
-        causal=causal, window=0, bq=bq, bkv=bkv, normalize=True,
-        interpret=interpret, out_dtype=q.dtype)
-    return out[:, 0]
+    call = pl.pallas_call(
+        body,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, g, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, g, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, g, sq, d), out_dtype),
+        ],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+    )
+    offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
+                         jnp.asarray(k_offset, jnp.int32)])
+    return tuple(call(offsets, klen.astype(jnp.int32), q, k, v, m, l, acc))

@@ -16,10 +16,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention.kernel import (
-    flash_carry,
-    largest_dividing_block,
-)
+from repro.kernels.flash_attention.kernel import flash_carry, sublane_block
 
 _WARNED_SHAPES: set = set()
 
@@ -29,8 +26,9 @@ def _on_tpu() -> bool:
 
 
 def _warn_shrunk_block(dim: int, preferred: int, what: str) -> int:
-    """Largest dividing block, warning once per (dim, preferred) pair."""
-    b = largest_dividing_block(dim, preferred)
+    """The kernel's block for this dim, warning once per (dim, preferred)
+    pair when it differs from the preferred one."""
+    b = sublane_block(dim, preferred)
     if b != min(preferred, dim) and (what, dim, preferred) not in _WARNED_SHAPES:
         _WARNED_SHAPES.add((what, dim, preferred))
         warnings.warn(
@@ -74,15 +72,15 @@ def _state_from_kernel(m4, l4, acc4, b, kvh, g):
 
 
 def _klen_vector(k_len, b, kvh, t_hi):
-    """Normalize k_len (None | scalar | [B] per-row) to [B*Kv, 1] int32."""
+    """Normalize k_len (None | scalar | [B] per-row) to [B*Kv] int32."""
     if k_len is None:
         kl = jnp.full((b,), t_hi, jnp.int32)
     else:
         kl = jnp.broadcast_to(jnp.asarray(k_len, jnp.int32), (b,))
-    return jnp.repeat(kl, kvh)[:, None]
+    return jnp.repeat(kl, kvh)
 
 
-def _carry_reference(q4, k3, v3, m4, l4, acc4, q_pos, k_pos, klen, *,
+def _carry_reference(q4, k3, v3, m4, l4, acc4, q_offset, k_offset, klen, *,
                      causal: bool, window: int):
     """jnp twin of ``flash_carry(normalize=False)`` over the whole KV block
     at once (one-shot softmax merge == the kernel's per-block online merge).
@@ -91,9 +89,9 @@ def _carry_reference(q4, k3, v3, m4, l4, acc4, q_pos, k_pos, klen, *,
     scale = 1.0 / (d ** 0.5)
     s = jnp.einsum("bgsd,btd->bgst", q4.astype(jnp.float32),
                    k3.astype(jnp.float32)) * scale
-    qp = q_pos[:, 0]
-    kp = k_pos[:, 0]
-    mask = kp[None, None, None, :] < klen[:, 0][:, None, None, None]
+    qp = q_offset + jnp.arange(q4.shape[2], dtype=jnp.int32)
+    kp = k_offset + jnp.arange(k3.shape[1], dtype=jnp.int32)
+    mask = kp[None, None, None, :] < klen[:, None, None, None]
     if causal:
         mask = jnp.logical_and(mask, (kp[None, :] <= qp[:, None])[None, None])
     if window:
@@ -115,10 +113,10 @@ def _carry_fused(causal: bool, window: int, bq: int, bkv: int,
     """The fused launch with a custom VJP: forward is the Pallas kernel,
     backward is the jnp oracle's gradient (Pallas has no JVP rule here, and
     the ring schedules are differentiated by the training loop)."""
-    def prim(q4, k3, v3, m4, l4, acc4, q_pos, k_pos, klen):
-        return flash_carry(q4, k3, v3, m4, l4, acc4, q_pos, k_pos, klen,
-                           causal=causal, window=window, bq=bq, bkv=bkv,
-                           normalize=False, interpret=interpret)
+    def prim(q4, k3, v3, m4, l4, acc4, q_offset, k_offset, klen):
+        return flash_carry(q4, k3, v3, m4, l4, acc4, q_offset, k_offset,
+                           klen, causal=causal, window=window, bq=bq,
+                           bkv=bkv, normalize=False, interpret=interpret)
 
     ref = functools.partial(_carry_reference, causal=causal, window=window)
     f = jax.custom_vjp(prim)
@@ -160,13 +158,10 @@ def flash_hop(q, k, v, state, *, q_offset=0, k_offset=0, k_len=None,
     _warn_shrunk_block(t, bkv, "T")
     q4, k3, v3 = _fold_gqa(q, k, v)
     m4, l4, acc4 = _state_to_kernel(state, b, kvh, g)
-    q_pos = (jnp.asarray(q_offset, jnp.int32)
-             + jnp.arange(sq, dtype=jnp.int32))[:, None]
-    k_pos = (jnp.asarray(k_offset, jnp.int32)
-             + jnp.arange(t, dtype=jnp.int32))[:, None]
     klen = _klen_vector(k_len, b, kvh, 2 ** 30)
     m4, l4, acc4 = _carry_fused(causal, window, bq, bkv, interpret)(
-        q4, k3, v3, m4, l4, acc4, q_pos, k_pos, klen)
+        q4, k3, v3, m4, l4, acc4, jnp.asarray(q_offset, jnp.int32),
+        jnp.asarray(k_offset, jnp.int32), klen)
     return _state_from_kernel(m4, l4, acc4, b, kvh, g)
 
 
@@ -185,11 +180,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     m0 = jnp.full((b * kvh, g, sq, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((b * kvh, g, sq, 1), jnp.float32)
     acc0 = jnp.zeros((b * kvh, g, sq, d), jnp.float32)
-    q_pos = jnp.arange(sq, dtype=jnp.int32)[:, None]
-    k_pos = jnp.arange(t, dtype=jnp.int32)[:, None]
-    klen = jnp.full((b * kvh, 1), t, jnp.int32)
+    klen = jnp.full((b * kvh,), t, jnp.int32)
     _, _, o4 = flash_carry(
-        q4, k3, v3, m0, l0, acc0, q_pos, k_pos, klen, causal=causal,
+        q4, k3, v3, m0, l0, acc0, 0, 0, klen, causal=causal,
         window=window, bq=bq, bkv=bkv, normalize=True,
         interpret=not _on_tpu(), out_dtype=q.dtype)
     return o4.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

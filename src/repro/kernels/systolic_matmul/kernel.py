@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
 
 def largest_dividing_block(dim: int, preferred: int) -> int:
     """Largest block size <= preferred that divides dim exactly (>= 1)."""
@@ -83,7 +81,7 @@ def matmul(a: jax.Array, b: jax.Array, acc: jax.Array | None = None, *,
     bk = largest_dividing_block(k, bk)
     out_dtype = out_dtype or a.dtype
     n_k = k // bk
-    params = pallas_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     if acc is None:
         kernel = functools.partial(_matmul_kernel, n_k=n_k)
@@ -109,6 +107,6 @@ def matmul(a: jax.Array, b: jax.Array, acc: jax.Array | None = None, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **({"compiler_params": params} if params else {}),
+        compiler_params=params,
     )
     return call(*operands)

@@ -33,18 +33,16 @@ import numpy as np
 import jax
 
 from repro.configs import ServeConfig, apply_overrides, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, split_tree
 from repro.serve.engine import ServeEngine
 from repro.serve.sharded_cache import RingShardedBackend
 
 
 def _make_mesh(spec: str):
-    from jax.sharding import Mesh
     d, m = (int(x) for x in spec.lower().split("x"))
-    n = d * m
-    devs = np.asarray(jax.devices()[:n]).reshape(d, m)
-    assert devs.size == n, f"need {n} devices for mesh {spec}"
-    return Mesh(devs, ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def main(argv=None):
@@ -139,4 +137,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -35,6 +35,7 @@ from repro.configs import (
     get_smoke_config,
 )
 from repro.data.pipeline import DataLoader, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.sharding.partitioning import shardings_from_axes
 from repro.train import step as step_lib
@@ -84,7 +85,10 @@ def main(argv=None):
         d, m = n_dev, 1
     mesh = make_mesh((d, m), ("data", "model"))
 
-    train_step = jax.jit(step_lib.make_train_step(cfg, tcfg, mesh))
+    # the state is donated: a step writes the new state into the old one's
+    # buffers instead of holding both (at full width two do not fit a chip)
+    train_step = jax.jit(step_lib.make_train_step(cfg, tcfg, mesh),
+                         donate_argnums=0)
     state_sds, state_axes = step_lib.state_shapes(cfg, tcfg, mesh)
 
     ckpt = CheckpointManager(tcfg.checkpoint_dir, keep=tcfg.keep_checkpoints,
@@ -184,4 +188,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

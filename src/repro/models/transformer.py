@@ -141,6 +141,26 @@ def block_decode(lp, x, cache, cfg: ModelConfig, moe_layer: bool = False,
     return x + y, cache
 
 
+def _scan_decode(layer_fn, x, params, cache):
+    """``layer_fn(lp, x, c) -> (x, c)`` over the stacked layers, with the
+    stacked cache in the loop carry: each layer's slice is read and written
+    back in place. (A scan with the cache as xs/ys would stack a second
+    cache, which at serving sizes does not fit next to the first.)"""
+    n = jax.tree_util.tree_leaves(cache)[0].shape[0]
+
+    def body(carry, inp):
+        x, cache = carry
+        lp, i = inp
+        x, c = layer_fn(lp, x, jax.tree_util.tree_map(lambda a: a[i], cache))
+        cache = jax.tree_util.tree_map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+            cache, c)
+        return (x, cache), None
+
+    (x, cache), _ = linkstats.scan(body, (x, cache), (params, jnp.arange(n)))
+    return x, cache
+
+
 # ---------------------------------------------------------------------------
 # Model
 # ---------------------------------------------------------------------------
@@ -324,23 +344,14 @@ class TransformerLM:
         new_cache = dict(cache)
 
         if cfg.first_k_dense:
-            def dbody(x, inp):
-                lp, c = inp
-                y, c2 = block_decode(lp, x, c, cfg, moe_layer=False,
-                                     active=active)
-                return y, c2
-            x, new_dense = linkstats.scan(
-                dbody, x, (params["dense_layers"], cache["dense_layers"]))
-            new_cache["dense_layers"] = new_dense
-
-        def body(x, inp):
-            lp, c = inp
-            y, c2 = block_decode(lp, x, c, cfg, moe_layer=self.moe,
-                                 active=active)
-            return y, c2
-        x, new_layers = linkstats.scan(
-            body, x, (params["layers"], cache["layers"]))
-        new_cache["layers"] = new_layers
+            x, new_cache["dense_layers"] = _scan_decode(
+                lambda lp, x, c: block_decode(lp, x, c, cfg, moe_layer=False,
+                                              active=active),
+                x, params["dense_layers"], cache["dense_layers"])
+        x, new_cache["layers"] = _scan_decode(
+            lambda lp, x, c: block_decode(lp, x, c, cfg, moe_layer=self.moe,
+                                          active=active),
+            x, params["layers"], cache["layers"])
 
         x = apply_norm(params["final_norm"], x, cfg)
         logits = lm_logits(params.get("head", {}), params["embed"], x, cfg)

@@ -233,7 +233,7 @@ def shard_call(body, mesh, in_specs, out_specs, *args):
     Armed: traces the instrumented body, ships per-PE stats out as an
     extra output sharded over *all* mesh axes, and absorbs the device
     totals into the active scope."""
-    from repro.compat import shard_map
+    from jax import shard_map
     if armed():
         fn = shard_map(instrumented(body), mesh=mesh, in_specs=in_specs,
                        out_specs=(out_specs, stats_specs(mesh.axis_names)),

@@ -10,9 +10,7 @@ Span taxonomy (the ``cat`` field groups them in the viewer):
 A :class:`Tracer` records complete-duration events (``ph: "X"``, ``ts``/
 ``dur`` in microseconds — the trace-event spec's unit) on the host clock.
 When a JAX profiler is attached, spans also annotate the device timeline
-via ``jax.profiler.TraceAnnotation`` (imported lazily; a missing/absent
-jax never breaks host tracing, so the numpy-only scheduler may trace
-too).
+via ``jax.profiler.TraceAnnotation``.
 
 Usage::
 
@@ -32,6 +30,8 @@ import json
 import time
 from contextlib import contextmanager
 from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Tracer:
@@ -54,11 +54,7 @@ class Tracer:
     def _annotation(self, name: str):
         if not self.device_annotations:
             return None
-        try:
-            from jax.profiler import TraceAnnotation
-            return TraceAnnotation(name)
-        except Exception:
-            return None
+        return TraceAnnotation(name)
 
     # ------------------------------------------------------------- spans
     @contextmanager

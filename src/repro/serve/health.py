@@ -2,9 +2,9 @@
 
 The :class:`HealthMonitor` wraps each engine tick in a guard:
 
-1. snapshot the scheduler's mutable tick state and the (immutable) cache
-   pytree — both are cheap: the cache snapshot is just a reference, and
-   the scheduler snapshot copies a few small host arrays;
+1. snapshot the scheduler's mutable tick state and the cache — the
+   scheduler snapshot copies a few small host arrays; the cache snapshot
+   is a device copy, since the step donates the live cache;
 2. plan + run the backend step, then judge it on three signals:
    the checked-link probe (``backend.link_health()``), the wall-clock
    deadline, and row-wise logit finiteness (``core/guard.py``);
@@ -144,7 +144,7 @@ class HealthMonitor:
         eng, hcfg = self.eng, self.hcfg
         self.tick += 1
         snap_sched = eng.sched.snapshot()
-        snap_cache = eng.backend.cache     # immutable pytree: a free copy
+        snap_cache = eng.backend.snapshot_cache()
 
         for _ in range(hcfg.max_retries + 1):
             tokens, active, sampling = eng.sched.plan()

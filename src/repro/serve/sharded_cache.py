@@ -34,6 +34,12 @@ Robustness surface (serve/health.py rides on it):
   how the health monitor migrates serving state one rung down the mode
   ladder without losing a token.
 
+Every jitted program that takes the cache donates it
+(:func:`jit_donating_cache`): the step, prefill and zero-row update write
+the new cache into the old one's buffers, so a tick holds one cache, not
+two. An array the caller kept of the old cache is deleted by the call —
+the health monitor snapshots with :meth:`DecodeBackend.snapshot_cache`.
+
 Telemetry surface (DESIGN.md §8): ``RingShardedBackend(...,
 telemetry=True)`` compiles the step/prefill with a
 :mod:`repro.obs.linkstats` scope armed and a 0/1 enable scalar as a jit
@@ -50,8 +56,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.core import faults, queues, topology
 from repro.obs import linkstats
@@ -63,6 +69,12 @@ from repro.sharding.partitioning import (
     serve_cache_shardings,
     shardings_from_axes,
 )
+
+
+def jit_donating_cache(fn, cache_argnum: int = 1):
+    """``jax.jit(fn)`` with the cache argument donated, so the program
+    updates the cache in place."""
+    return jax.jit(fn, donate_argnums=cache_argnum)
 
 
 class DecodeBackend:
@@ -81,9 +93,9 @@ class DecodeBackend:
         self.max_seq = scfg.max_seq_len
         self.params = self._place_params(params)
         self.cache = self._init_cache()
-        self._step = jax.jit(self._make_step())
-        self._zero = jax.jit(self._make_zero_row())
-        self._prefill = jax.jit(self._make_prefill()) \
+        self._step = jit_donating_cache(self._make_step())
+        self._zero = jit_donating_cache(self._make_zero_row(), 0)
+        self._prefill = jit_donating_cache(self._make_prefill()) \
             if self.supports_prefill else None
 
     # ---------------------------------------------------------- placement
@@ -91,7 +103,10 @@ class DecodeBackend:
         return params
 
     def _init_cache(self):
-        return self.model.init_cache(self.max_batch, self.max_seq)
+        # one program writes the cache straight into its buffers (built
+        # eagerly, its per-layer pieces and their stack would coexist)
+        return jax.jit(self.model.init_cache, static_argnums=(0, 1))(
+            self.max_batch, self.max_seq)
 
     # -------------------------------------------------------------- steps
     def _make_step(self):
@@ -128,10 +143,18 @@ class DecodeBackend:
         bit-identically to a fresh engine."""
         self.cache = self._zero(self.cache, slot)
 
+    def snapshot_cache(self):
+        """A copy of the cache that outlives the next (donating) step."""
+        return jax.tree_util.tree_map(
+            lambda l: jax.device_put(l, l.sharding, may_alias=False),
+            self.cache)
+
     def adopt_cache(self, cache) -> None:
         """Take over a cache snapshot from another backend (mode-ladder
-        degradation): place it wherever this backend keeps its cache."""
-        self.cache = jax.device_put(cache)
+        degradation): place a copy wherever this backend keeps its cache,
+        so the snapshot survives the steps that donate it."""
+        self.cache = jax.tree_util.tree_map(
+            lambda l: jax.device_put(l, may_alias=False), cache)
 
     def link_health(self) -> dict:
         """Per-class link error counts of the last step's probe (empty for
@@ -221,10 +244,10 @@ class RingShardedBackend(DecodeBackend):
         return jax.device_put(params, sh)
 
     def _init_cache(self):
-        cache = self.model.init_cache(self.max_batch, self.max_seq)
         sh = serve_cache_shardings(self.model, self.max_batch, self.max_seq,
                                    self.mesh, ring=True)
-        return jax.device_put(cache, sh)
+        return jax.jit(self.model.init_cache, static_argnums=(0, 1),
+                       out_shardings=sh)(self.max_batch, self.max_seq)
 
     def _make_step(self):
         model, mesh = self.model, self.mesh
@@ -355,4 +378,4 @@ class RingShardedBackend(DecodeBackend):
 
     def adopt_cache(self, cache) -> None:
         sh = jax.tree_util.tree_map(lambda l: l.sharding, self.cache)
-        self.cache = jax.device_put(cache, sh)
+        self.cache = jax.device_put(cache, sh, may_alias=False)

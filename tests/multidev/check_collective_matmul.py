@@ -12,9 +12,9 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import queues
 from repro.core.collective_matmul import (
     cannon_matmul,
@@ -24,6 +24,7 @@ from repro.core.collective_matmul import (
     systolic_ffn,
 )
 from repro.core.topology import chains, ring, torus_shift
+from repro.launch.mesh import make_mesh
 
 results = {}
 
@@ -32,7 +33,7 @@ def record(name, ok, detail=""):
     results[name] = {"ok": bool(ok), "detail": str(detail)}
 
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 n = 4
 
 # --- ring_ag_matmul vs reference -------------------------------------------

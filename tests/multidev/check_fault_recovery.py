@@ -27,11 +27,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs import ServeConfig, get_smoke_config
 from repro.core import faults, queues
 from repro.core.topology import ring
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, split_tree
 from repro.serve.engine import ServeEngine
 from repro.serve.health import HealthConfig
@@ -47,7 +48,7 @@ def record(name, ok, detail=""):
 # --- 1. checked-link detection matrix under shard_map -----------------------
 N = 8
 FAULT_HOP, FAULT_DEV = 2, 5
-pe_mesh = jax.make_mesh((N,), ("pe",))
+pe_mesh = make_mesh((N,), ("pe",))
 topo = ring("pe", N)
 payload = (jnp.arange(N * 4, dtype=jnp.float32).reshape(N, 4) + 1.0) / 3.0
 
@@ -107,8 +108,8 @@ cfg = get_smoke_config("qwen3-0.6b")
 scfg = ServeConfig(max_batch=2, max_seq_len=32, temperature=0.0)
 model = build_model(cfg)
 params, _ = split_tree(model.init(jax.random.PRNGKey(0)))
-serve_mesh = jax.make_mesh((1, 4), ("data", "model"),
-                           devices=jax.devices()[:4])
+serve_mesh = make_mesh((1, 4), ("data", "model"),
+                       devices=jax.devices()[:4])
 FAULT_TICK = 3
 
 

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.configs import ServeConfig, get_smoke_config
 from repro.core.ring_attention import systolic_ring_decode
+from repro.launch.mesh import make_mesh
 from repro.obs import linkstats
 from repro.obs.trace import Tracer
 from repro.models import build_model, split_tree
@@ -44,7 +45,7 @@ def record(name, ok, detail=""):
     results[name] = {"ok": bool(ok), "detail": str(detail)}
 
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_smoke_config("qwen3-0.6b")
 scfg = ServeConfig(max_batch=8, max_seq_len=64, temperature=0.0)
 model = build_model(cfg)

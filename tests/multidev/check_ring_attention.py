@@ -16,6 +16,7 @@ from repro.core.ring_attention import (
     ring_attn_applicable,
     systolic_ring_attention,
 )
+from repro.launch.mesh import make_mesh
 
 results = {}
 
@@ -45,7 +46,7 @@ def ref_attention(q, k, v, *, causal=True, window=0):
     return out
 
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 key = jax.random.PRNGKey(0)
 k1, k2, k3 = jax.random.split(key, 3)

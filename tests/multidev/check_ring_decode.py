@@ -19,10 +19,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ServeConfig, get_smoke_config
 from repro.core.ring_attention import ring_decode_applicable, systolic_ring_decode
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, split_tree
 from repro.serve.engine import ServeEngine
 from repro.serve.sharded_cache import RingShardedBackend
@@ -34,7 +35,7 @@ def record(name, ok, detail=""):
     results[name] = {"ok": bool(ok), "detail": str(detail)}
 
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 MODES = ("baseline", "sw", "xqueue", "qlr")
 
 # --- 1. decode core vs dense masked attention ------------------------------

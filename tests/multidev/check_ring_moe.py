@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.ring_moe import MODES, ring_moe_applicable, systolic_ring_moe
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_lib
 from repro.models.common import split_tree, use_sharding
 
@@ -24,7 +25,7 @@ def record(name, ok, detail=""):
     results[name] = {"ok": bool(ok), "detail": str(detail)}
 
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 CFG = ModelConfig(
     name="ring-moe-check", family="moe", d_model=16, d_ff=32, d_ff_expert=32,

@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import queues
 from repro.core.collective_matmul import (
     cannon_matmul,
@@ -31,6 +31,7 @@ from repro.core.topology import (
     ring,
     torus_shift,
 )
+from repro.launch.mesh import make_mesh
 
 results = {}
 
@@ -42,7 +43,7 @@ def record(name, ok, detail=""):
 TOPOS = ("snake_fold", "torus2d", "cannon_grid")
 LINK_MODES = ("sw", "xqueue", "qlr")
 
-mesh = jax.make_mesh((8,), ("model",))     # grids fold 2x4
+mesh = make_mesh((8,), ("model",))     # grids fold 2x4
 n = 8
 
 # --- ring attention: any full-coverage visit order preserves the online
@@ -187,7 +188,7 @@ except (TypeError, AssertionError) as e:
     record("grid_decode_raises", True, type(e).__name__)
 
 # --- Cannon: one-hop grid skew == masked-rotation skew (2x2 on model=4) -----
-mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+mesh24 = make_mesh((2, 4), ("data", "model"))
 rows = cols = 2
 rt = torus_shift("model", rows, cols, direction="right")
 ct = torus_shift("model", rows, cols, direction="down")

@@ -284,3 +284,16 @@ def test_tile_matmul_acc_carry():
     assert y.dtype == ref.dtype
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), rtol=1e-4,
                                atol=1e-2)
+
+
+@pytest.mark.parametrize("dim,pref,want", [
+    (256, 128, 128), (192, 128, 96), (1, 128, 1), (600, 128, 120),
+    (200, 128, 40), (12, 128, 12), (7, 128, 7),
+])
+def test_flash_sublane_block(dim, pref, want):
+    """Row blocks of the flash kernel divide the dim and are a multiple of
+    8, or the whole dim (the TPU's second-minor tiling rule)."""
+    from repro.kernels.flash_attention.kernel import sublane_block
+    b = sublane_block(dim, pref)
+    assert b == want
+    assert dim % b == 0 and (b % 8 == 0 or b == dim)

@@ -353,3 +353,29 @@ def test_dense_block_prefill_matches_streaming(qwen):
                                    prefill_chunk=8))
     assert out == ref
     assert t_block < t_stream
+
+
+@pytest.mark.parametrize("prefill_chunk", [0, 8])
+def test_backend_donates_cache_and_snapshot_survives(qwen, prefill_chunk):
+    """The step (and block prefill) update the cache in place: the array a
+    caller kept of the old cache is deleted, while ``snapshot_cache`` gives
+    a copy that outlives the step and ``adopt_cache`` takes it back."""
+    cfg, model, params = qwen
+    scfg = ServeConfig(max_batch=2, max_seq_len=32, temperature=0.0,
+                       prefill_chunk=prefill_chunk)
+    eng = ServeEngine(cfg, scfg, params)
+    eng.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=3)
+    eng._admit()
+    snap = eng.backend.snapshot_cache()
+    old = eng.backend.cache
+    tokens, active, _ = eng.sched.plan()
+    eng.backend.step(tokens, active)
+    assert all(l.is_deleted() for l in jax.tree_util.tree_leaves(old))
+    assert not any(l.is_deleted() for l in jax.tree_util.tree_leaves(snap))
+    after = jax.tree_util.tree_map(np.asarray, eng.backend.cache)
+    eng.backend.adopt_cache(snap)
+    eng.backend.step(tokens, active)            # replay the same tick
+    for a, b in zip(jax.tree_util.tree_leaves(after),
+                    jax.tree_util.tree_leaves(eng.backend.cache)):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    assert not any(l.is_deleted() for l in jax.tree_util.tree_leaves(snap))

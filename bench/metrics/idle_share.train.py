@@ -1,0 +1,6 @@
+"""Share of the training window in which no operation ran on the device."""
+from bench.layer import idle_percent
+
+
+def read(ctx):
+    return idle_percent(ctx)

@@ -1,0 +1,7 @@
+"""Device milliseconds per call of the train step program."""
+from bench.layer import per_call_s
+
+
+def read(ctx):
+    s = per_call_s(ctx, "train_step")
+    return None if s is None else 1e3 * s

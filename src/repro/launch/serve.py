@@ -18,14 +18,17 @@ degradation); --deadline SECONDS adds a wall-clock budget per step;
 
 Observability flags (DESIGN.md §8): --metrics-out FILE.json writes the
 metrics snapshot (a FILE.prom Prometheus text twin lands next to it);
---trace-out FILE.json writes a Chrome trace of the engine's tick phases
-(load it in Perfetto or chrome://tracing); --telemetry arms link-traffic
-counters on the ring backend (queue push/pop, payload bytes, checked-link
-errors) folded into the metrics as repro_link_*.
+--trace-out DIR records a profiler trace of the run there: the engine's
+serve.* spans (repro.obs.trace) and the device's operations on one
+timeline (open it in TensorBoard's profile plugin or Perfetto);
+--telemetry arms link-traffic counters on the ring backend (queue
+push/pop, payload bytes, checked-link errors) folded into the metrics as
+repro_link_*.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import numpy as np
@@ -74,7 +77,8 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default="",
                     help="write metrics snapshot JSON here (+ .prom twin)")
     ap.add_argument("--trace-out", default="",
-                    help="write Chrome trace-event JSON here (Perfetto)")
+                    help="record a profiler trace of the run in this "
+                         "directory")
     ap.add_argument("--telemetry", action="store_true",
                     help="arm link-traffic telemetry (ring only)")
     args = ap.parse_args(argv)
@@ -95,12 +99,7 @@ def main(argv=None):
     if args.monitor or args.deadline > 0:
         from repro.serve.health import HealthConfig
         health = HealthConfig(deadline_s=args.deadline)
-    tracer = None
-    if args.trace_out:
-        from repro.obs.trace import Tracer
-        tracer = Tracer()
-    engine = ServeEngine(cfg, scfg, params, backend=backend, health=health,
-                         tracer=tracer)
+    engine = ServeEngine(cfg, scfg, params, backend=backend, health=health)
 
     rng = np.random.default_rng(0)
     reqs = []
@@ -110,9 +109,11 @@ def main(argv=None):
         engine.submit(prompt, max_new_tokens=args.max_new)
     reqs = list(engine.pending)
 
-    t0 = time.perf_counter()
-    ticks = engine.run()
-    dt = time.perf_counter() - t0
+    with (jax.profiler.trace(args.trace_out) if args.trace_out
+          else contextlib.nullcontext()):
+        t0 = time.perf_counter()
+        ticks = engine.run()
+        dt = time.perf_counter() - t0
     total_new = sum(len(r.out_tokens) for r in reqs)
     print(f"served {len(reqs)} requests ({engine.backend.name}), "
           f"{total_new} tokens, {ticks} engine ticks, "
@@ -126,14 +127,13 @@ def main(argv=None):
         for ev in engine.monitor.events:
             print(f"  tick={ev.tick} [{ev.kind}] mode={ev.mode}: {ev.detail}")
 
-    if args.metrics_out or args.trace_out:
-        prom = (args.metrics_out.rsplit(".", 1)[0] + ".prom"
-                if args.metrics_out else None)
-        engine.export_observability(
-            metrics_json=args.metrics_out or None, metrics_prom=prom,
-            trace_out=args.trace_out or None)
-        for p in filter(None, (args.metrics_out, prom, args.trace_out)):
-            print(f"wrote {p}")
+    if args.metrics_out:
+        prom = args.metrics_out.rsplit(".", 1)[0] + ".prom"
+        engine.export_observability(metrics_json=args.metrics_out,
+                                    metrics_prom=prom)
+        print(f"wrote {args.metrics_out}\nwrote {prom}")
+    if args.trace_out:
+        print(f"wrote a profiler trace under {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -11,12 +11,14 @@ hook (SIGTERM), straggler watchdog, metrics JSONL.
 
 Observability (DESIGN.md §8): --metrics-out FILE.json snapshots the run's
 obs registry (steps/tokens counters, loss/lr gauges, step-time histogram)
-as JSON plus a FILE.prom Prometheus twin; --trace-out FILE.json writes a
-Chrome trace of the step phases (data / step / checkpoint) for Perfetto.
+as JSON plus a FILE.prom Prometheus twin; --trace-out DIR records a
+profiler trace of the run there: the train.* spans (data, step,
+checkpoint, straggler; repro.obs.trace) on the device's timeline.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 from dataclasses import replace
@@ -65,7 +67,8 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default="",
                     help="write metrics snapshot JSON here (+ .prom twin)")
     ap.add_argument("--trace-out", default="",
-                    help="write Chrome trace-event JSON here (Perfetto)")
+                    help="record a profiler trace of the run in this "
+                         "directory")
     args = ap.parse_args(argv)
 
     if args.multihost:
@@ -124,55 +127,59 @@ def main(argv=None):
     tokens_per_step = args.batch * args.seq
 
     from repro.obs import metrics as obs_metrics
-    from repro.obs.trace import NullTracer, Tracer
+    from repro.obs import trace
     registry = obs_metrics.Registry()
-    tracer = Tracer() if args.trace_out else NullTracer()
     step_hist = registry.histogram("repro_train_step_seconds",
                                    "train step wall time")
 
-    for step_i in range(start_step, args.steps):
-        with tracer.span("data", cat="train"):
-            batch = next(loader)
-            batch = jax.device_put(
-                {k: jnp.asarray(v) for k, v in batch.items()})
-        timer.start()
-        with tracer.span("step", cat="train", args={"step": step_i}):
-            state, metrics = train_step(state, batch)
-            metrics = jax.tree_util.tree_map(np.asarray, metrics)
-        dt, slow = timer.stop()
-        step_hist.observe(dt)
-        registry.counter("repro_train_steps_total", "train steps run").inc()
-        registry.counter("repro_train_tokens_total",
-                         "tokens consumed").inc(tokens_per_step)
-        registry.gauge("repro_train_loss", "last logged loss").set(
-            float(metrics["loss"]))
-        registry.gauge("repro_train_lr", "last learning rate").set(
-            float(metrics["lr"]))
-        registry.gauge("repro_train_tokens_per_second",
-                       "tokens / step wall time").set(
-            tokens_per_step / max(dt, 1e-9))
-        if slow:
-            tracer.instant("straggler", cat="train",
-                           args={"step": step_i, "seconds": dt})
-            registry.counter("repro_train_stragglers_total",
-                             "steps past the watchdog deadline").inc()
-            print(f"[watchdog] step {step_i} took {dt:.2f}s "
-                  f"(deadline {tcfg.straggler_deadline_s}s)")
-        if step_i % tcfg.log_every == 0 or step_i == args.steps - 1:
-            logger.log(step_i, loss=float(metrics["loss"]),
-                       grad_norm=float(metrics["grad_norm"]),
-                       lr=float(metrics["lr"]),
-                       tok_per_s=tokens_per_step / max(dt, 1e-9),
-                       step_s=dt)
-        if tcfg.checkpoint_every and (step_i + 1) % tcfg.checkpoint_every == 0:
-            with tracer.span("checkpoint", cat="train",
-                             args={"step": step_i + 1}):
-                ckpt.save(step_i + 1, state,
-                          extra={"data_state": loader.state_dict()})
-    with tracer.span("checkpoint", cat="train", args={"step": args.steps}):
-        ckpt.save(args.steps, state,
-                  extra={"data_state": loader.state_dict()})
-        ckpt.wait()
+    profile = (jax.profiler.trace(args.trace_out) if args.trace_out
+               else contextlib.nullcontext())
+    with profile:
+        for step_i in range(start_step, args.steps):
+            with trace.span("train.data"):
+                batch = next(loader)
+                batch = jax.device_put(
+                    {k: jnp.asarray(v) for k, v in batch.items()})
+            timer.start()
+            with trace.span("train.step") as sp:
+                if trace.enabled():
+                    sp.set_metadata(step=step_i)
+                state, metrics = train_step(state, batch)
+                metrics = jax.tree_util.tree_map(np.asarray, metrics)
+            dt, slow = timer.stop()
+            step_hist.observe(dt)
+            registry.counter("repro_train_steps_total",
+                             "train steps run").inc()
+            registry.counter("repro_train_tokens_total",
+                             "tokens consumed").inc(tokens_per_step)
+            registry.gauge("repro_train_loss", "last logged loss").set(
+                float(metrics["loss"]))
+            registry.gauge("repro_train_lr", "last learning rate").set(
+                float(metrics["lr"]))
+            registry.gauge("repro_train_tokens_per_second",
+                           "tokens / step wall time").set(
+                tokens_per_step / max(dt, 1e-9))
+            if slow:
+                trace.instant("train.straggler", step=step_i, seconds=dt)
+                registry.counter("repro_train_stragglers_total",
+                                 "steps past the watchdog deadline").inc()
+                print(f"[watchdog] step {step_i} took {dt:.2f}s "
+                      f"(deadline {tcfg.straggler_deadline_s}s)")
+            if step_i % tcfg.log_every == 0 or step_i == args.steps - 1:
+                logger.log(step_i, loss=float(metrics["loss"]),
+                           grad_norm=float(metrics["grad_norm"]),
+                           lr=float(metrics["lr"]),
+                           tok_per_s=tokens_per_step / max(dt, 1e-9),
+                           step_s=dt)
+            if tcfg.checkpoint_every and \
+                    (step_i + 1) % tcfg.checkpoint_every == 0:
+                with trace.span("train.checkpoint"):
+                    ckpt.save(step_i + 1, state,
+                              extra={"data_state": loader.state_dict()})
+        with trace.span("train.checkpoint"):
+            ckpt.save(args.steps, state,
+                      extra={"data_state": loader.state_dict()})
+            ckpt.wait()
     loader.close()
     logger.close()
     if args.metrics_out:
@@ -181,8 +188,7 @@ def main(argv=None):
         registry.dump_prometheus(prom)
         print(f"wrote {args.metrics_out}\nwrote {prom}")
     if args.trace_out:
-        tracer.dump(args.trace_out)
-        print(f"wrote {args.trace_out}")
+        print(f"wrote a profiler trace under {args.trace_out}")
     print(f"done: {args.steps} steps; watchdog {timer.summary()}")
     return state
 

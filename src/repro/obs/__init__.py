@@ -7,7 +7,8 @@ the rest):
   linkstats    — per-PE queue-traffic counters riding inside jit
   utilization  — LinkStats + roofline FLOPs + energy models → per-mode
                  compute-unit utilization % and modeled GOPS/W
-  trace        — host-side spans → Chrome trace-event JSON (Perfetto)
+  trace        — host spans and a compile counter on the profiler's
+                 clock (jax.profiler.TraceAnnotation)
   metrics      — counters / gauges / histograms registry → JSON +
                  Prometheus text exposition
 """

@@ -12,7 +12,7 @@ Instrument names follow Prometheus conventions, with units in the name:
               repro_degradations_total, repro_evictions_total,
               repro_link_tag_errors_total, repro_link_csum_errors_total, ...
   gauges      repro_active_slots, repro_queue_depth, repro_mode_rung, ...
-  histograms  repro_tick_latency_seconds, repro_prefill_latency_seconds
+  histograms  repro_tick_latency_seconds, repro_train_step_seconds
               (p50/p90/p99 via reservoir quantiles)
 
 Usage::
@@ -23,10 +23,6 @@ Usage::
         engine.step()
     reg.to_json()          # snapshot dict
     reg.to_prometheus()    # text exposition
-
-Snapshots are mergeable (``Registry.merge``): counters add, gauges take
-the other's latest value, histograms pool their samples — so per-phase or
-per-process snapshots can be combined into one report.
 """
 from __future__ import annotations
 
@@ -201,24 +197,6 @@ class Registry:
     def dump_prometheus(self, path) -> None:
         with open(path, "w") as f:
             f.write(self.to_prometheus())
-
-    # ------------------------------------------------------------- merge
-    def merge(self, other: "Registry") -> "Registry":
-        """Fold another registry into this one: counters add, gauges take
-        ``other``'s value, histograms pool retained samples and exact
-        count/sum. Returns self."""
-        for n, c in other._counters.items():
-            self.counter(n, c.help).value += c.value
-        for n, g in other._gauges.items():
-            self.gauge(n, g.help).set(g.value)
-        for n, h in other._histograms.items():
-            mine = self.histogram(n, h.help, h.max_samples)
-            mine.count += h.count
-            mine.sum += h.sum
-            mine._samples.extend(h._samples)
-            if len(mine._samples) > mine.max_samples:
-                del mine._samples[: len(mine._samples) - mine.max_samples]
-        return self
 
 
 _DEFAULT: Optional[Registry] = None

@@ -1,116 +1,90 @@
-"""Host-side tracing: engine-tick and train-step phases as Chrome
-trace-event JSON, viewable in Perfetto / chrome://tracing (DESIGN.md §8).
+"""Spans and counters on the profiler's clock (DESIGN.md §8).
 
-Span taxonomy (the ``cat`` field groups them in the viewer):
+Every span is a ``jax.profiler.TraceAnnotation``. With a profiler attached
+(``jax.profiler.trace``; ``launch/serve.py --trace-out DIR``) it lands in
+the trace's host plane on the same timeline as the device's operations,
+so one Perfetto or TensorBoard view shows what the host did while the
+device ran or stood idle. With none attached a span records nothing and
+costs about a microsecond. Metadata is attached only while a profiler
+records (:func:`enabled`), so the off path computes none of it; it shows
+up as the event's stats in ``jax.profiler.ProfileData``.
 
-  serve   tick, prefill, decode, sample, probe, rollback, degrade, evict
-  train   step, data, forward-backward, update, eval
-  bench   one span per timed sweep point
+Names are dotted and stable, since tools key on them; metadata in
+brackets:
 
-A :class:`Tracer` records complete-duration events (``ph: "X"``, ``ts``/
-``dur`` in microseconds — the trace-event spec's unit) on the host clock.
-When a JAX profiler is attached, spans also annotate the device timeline
-via ``jax.profiler.TraceAnnotation``.
+  serve.admit                one per ``ServeEngine._admit`` call
+    serve.admit_request      [rid, slot, queued_s, prefill_tokens, compiles]
+      serve.slot_reset
+      serve.prefill.dispatch ends at enqueue, before the device finishes
+  serve.tick                 [tick, active, sampling, prompt_rows, compiles]
+    serve.plan
+    serve.step.dispatch      ends at enqueue
+    serve.probe              checked ring backends
+    serve.sample.dispatch    the key split and the sampler's ops
+    serve.device_wait        the host blocks on the device here
+    serve.commit
+  serve.<event>              zero-work: rollback, link_fault, deadline,
+                             nonfinite, degrade [tick, detail]
+  train.data, train.step [step], train.checkpoint,
+  train.straggler [step, seconds]
+
+``queued_s`` runs from ``Scheduler.submit`` to the start of the request's
+admission; ``active``, ``sampling`` and ``prompt_rows`` count the tick's
+rows that decode, that sample, and that feed a prompt token; ``compiles``
+counts the backend compiles in the span (:func:`compiles`), which names
+the step that recompiled.
 
 Usage::
 
-    tr = Tracer()
-    with tr.span("tick", cat="serve", args={"tick": 3}):
-        with tr.span("decode", cat="serve"):
-            ...
-    tr.instant("rollback", cat="serve")       # zero-duration marker
-    tr.dump(path)                             # {"traceEvents": [...]}
-
-The clock is injectable (``Tracer(clock=...)``) so golden-file tests can
-produce deterministic timestamps.
+    with trace.span("serve.tick") as sp:
+        on = trace.enabled()
+        c0 = trace.compiles() if on else 0
+        ...
+        if on:
+            sp.set_metadata(tick=n, compiles=trace.compiles() - c0)
 """
 from __future__ import annotations
 
-import json
-import time
-from contextlib import contextmanager
-from typing import Callable, Optional
-
+import jax
 from jax.profiler import TraceAnnotation
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-class Tracer:
-    """Collects trace events in memory; thread-naive by design (the serve
-    engine and train loop are single-threaded hosts)."""
-
-    def __init__(self, clock: Optional[Callable[[], float]] = None,
-                 pid: int = 1, tid: int = 1, device_annotations: bool = True):
-        self._clock = clock or time.perf_counter
-        self._t0 = self._clock()
-        self.pid = pid
-        self.tid = tid
-        self.device_annotations = device_annotations
-        self.events: list = []
-
-    # ------------------------------------------------------------ helpers
-    def _now_us(self) -> float:
-        return (self._clock() - self._t0) * 1e6
-
-    def _annotation(self, name: str):
-        if not self.device_annotations:
-            return None
-        return TraceAnnotation(name)
-
-    # ------------------------------------------------------------- spans
-    @contextmanager
-    def span(self, name: str, cat: str = "repro", args: Optional[dict] = None):
-        """A complete-duration event around the block. Nests naturally —
-        Perfetto stacks same-tid spans by containment."""
-        start = self._now_us()
-        ann = self._annotation(name)
-        if ann is not None:
-            ann.__enter__()
-        try:
-            yield
-        finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            self.events.append({
-                "name": name, "cat": cat, "ph": "X",
-                "ts": start, "dur": self._now_us() - start,
-                "pid": self.pid, "tid": self.tid,
-                **({"args": args} if args else {}),
-            })
-
-    def instant(self, name: str, cat: str = "repro",
-                args: Optional[dict] = None) -> None:
-        """Zero-duration marker (rollbacks, degradations, evictions)."""
-        self.events.append({
-            "name": name, "cat": cat, "ph": "i",
-            "ts": self._now_us(), "s": "t",
-            "pid": self.pid, "tid": self.tid,
-            **({"args": args} if args else {}),
-        })
-
-    # ------------------------------------------------------------ export
-    def to_chrome(self) -> dict:
-        """JSON-object trace format: ts-sorted events plus metadata."""
-        return {
-            "traceEvents": sorted(self.events, key=lambda e: e["ts"]),
-            "displayTimeUnit": "ms",
-        }
-
-    def dump(self, path) -> None:
-        with open(path, "w") as f:
-            json.dump(self.to_chrome(), f, indent=1)
-            f.write("\n")
+_compiles = 0
+_listening = False
 
 
-class NullTracer(Tracer):
-    """Tracing disabled: same surface, records nothing, never touches the
-    clock or the profiler — the default wherever a tracer is optional."""
+def span(name: str) -> TraceAnnotation:
+    """A host span named ``name``: use as a context manager; attach
+    metadata with its ``set_metadata`` under :func:`enabled`."""
+    return TraceAnnotation(name)
 
-    def __init__(self):
-        super().__init__(clock=lambda: 0.0, device_annotations=False)
 
-    @contextmanager
-    def span(self, name, cat="repro", args=None):
-        yield
+def enabled() -> bool:
+    """Whether a profiler is recording, so metadata is worth computing."""
+    return TraceAnnotation.is_enabled()
 
-    def instant(self, name, cat="repro", args=None):
-        pass
+
+def instant(name: str, **meta) -> None:
+    """A zero-work span carrying ``meta`` (rollbacks, degradations,
+    evictions, stragglers)."""
+    with span(name) as sp:
+        if meta and enabled():
+            sp.set_metadata(**meta)
+
+
+def _on_duration(event: str, duration_s: float, **kwargs) -> None:
+    global _compiles
+    if event == BACKEND_COMPILE_EVENT:
+        _compiles += 1
+
+
+def compiles() -> int:
+    """Backend compiles in this process (persistent-cache loads included)
+    since the first call, which registers the ``jax.monitoring`` listener
+    that counts them; take differences around a span."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    return _compiles

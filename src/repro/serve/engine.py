@@ -23,10 +23,10 @@ dry-run cells lower exactly this step function at production size.
 Observability (DESIGN.md §8): the engine owns a metrics
 :class:`~repro.obs.metrics.Registry` (tokens, ticks, tick-latency
 histogram, plus the scheduler's request-lifecycle counters and the health
-monitor's rollback/degrade counters) and an optional
-:class:`~repro.obs.trace.Tracer` that spans each tick's phases
-(prefill / decode / sample; the monitor adds probe / rollback / degrade /
-evict marks). Pass ``tracer=None`` for the zero-cost null tracer.
+monitor's rollback/degrade counters) and marks each layer boundary with a
+:mod:`repro.obs.trace` span on the profiler's clock: ``serve.admit`` and
+one ``serve.admit_request`` per admission, ``serve.tick`` and its phases
+(plan, step dispatch, sample dispatch, device wait, commit).
 """
 from __future__ import annotations
 
@@ -38,7 +38,7 @@ import jax
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import NullTracer, Tracer
+from repro.obs import trace
 from repro.serve.sample import sample
 from repro.serve.scheduler import Request, Scheduler  # noqa: F401 (re-export)
 from repro.serve.sharded_cache import DecodeBackend
@@ -56,17 +56,14 @@ class TicksExhaustedError(RuntimeError):
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, scfg: ServeConfig, params,
                  backend: DecodeBackend | None = None, health=None,
-                 metrics: obs_metrics.Registry | None = None,
-                 tracer: Tracer | None = None):
+                 metrics: obs_metrics.Registry | None = None):
         self.cfg = cfg
         self.scfg = scfg
         self._params = params                  # kept for backend rebuilds
         self.metrics = metrics if metrics is not None \
             else obs_metrics.Registry()
-        self.tracer = tracer if tracer is not None else NullTracer()
         self.backend = backend if backend is not None \
             else DecodeBackend(cfg, scfg, params)
-        self.backend.tracer = self.tracer
         self.sched = Scheduler(scfg.max_batch, scfg.max_seq_len,
                                bos_token=scfg.bos_token,
                                eos_token=scfg.eos_token,
@@ -112,53 +109,79 @@ class ServeEngine:
 
     # ---------------------------------------------------------- scheduler
     def _admit(self):
-        for slot, req in self.sched.admit():
-            self.backend.free_slot(slot)
+        with trace.span("serve.admit"):
+            for slot, req in self.sched.admit():
+                self._admit_request(slot, req)
+
+    def _admit_request(self, slot: int, req: Request) -> None:
+        """Reset ``slot``'s cache rows and block-prefill what of ``req``'s
+        prompt the backend takes in one program."""
+        with trace.span("serve.admit_request") as sp:
+            on = trace.enabled()
+            if on:
+                queued_s = time.perf_counter() - req.t_submit
+                c0 = trace.compiles()
+            with trace.span("serve.slot_reset"):
+                self.backend.free_slot(slot)
             n_block = self.backend.prefill_len(len(req.prompt))
             if n_block > 0:
-                with self.tracer.span("prefill", cat="serve",
-                                      args={"slot": slot, "rid": req.rid,
-                                            "tokens": n_block}), \
-                        self.metrics.histogram(
-                            "repro_prefill_latency_seconds",
-                            "block-prefill wall time").time():
+                with trace.span("serve.prefill.dispatch"):
                     self.backend.prefill(slot, req.prompt[:n_block])
                 self.sched.note_prefilled(slot, n_block)
                 self.metrics.counter(
                     "repro_prefill_tokens_total",
                     "prompt tokens absorbed by block prefill").inc(n_block)
+            if on:
+                sp.set_metadata(rid=req.rid, slot=slot, queued_s=queued_s,
+                                prefill_tokens=n_block,
+                                compiles=trace.compiles() - c0)
 
     def _sample_and_commit(self, logits, sampling):
-        with self.tracer.span("sample", cat="serve"):
+        with trace.span("serve.sample.dispatch"):
             self.key, sub = jax.random.split(self.key)
-            next_tok = np.asarray(sample(logits, sub, self.scfg.temperature,
-                                         self.scfg.top_k))
+            next_tok = sample(logits, sub, self.scfg.temperature,
+                              self.scfg.top_k)
+        with trace.span("serve.device_wait"):
+            next_tok = np.asarray(next_tok)
+        with trace.span("serve.commit"):
             self.sched.commit(sampling, next_tok)
         self.metrics.counter("repro_tokens_total",
                              "tokens sampled and committed").inc(
             int(np.sum(sampling)))
+
+    def _plain_step(self):
+        """An unguarded tick; returns its (active, sampling) rows."""
+        with trace.span("serve.plan"):
+            tokens, active, sampling = self.sched.plan()
+        with trace.span("serve.step.dispatch"):
+            logits = self.backend.step(tokens, active)
+        self._sample_and_commit(logits, sampling)
+        return active, sampling
 
     def step(self):
         """One engine tick = one backend decode step for all slots (under
         the health monitor's guard when one is configured)."""
         self._tick += 1
         self.metrics.counter("repro_ticks_total", "engine ticks run").inc()
-        with self.tracer.span("tick", cat="serve",
-                              args={"tick": self._tick}), \
+        with trace.span("serve.tick") as sp, \
                 self.metrics.histogram("repro_tick_latency_seconds",
                                        "whole-tick wall time").time():
-            if self.monitor is not None:
-                return self.monitor.guarded_step()
-            tokens, active, sampling = self.sched.plan()
-            with self.tracer.span("decode", cat="serve"):
-                logits = self.backend.step(tokens, active)
-            self._sample_and_commit(logits, sampling)
+            on = trace.enabled()
+            c0 = trace.compiles() if on else 0
+            active, sampling = (self.monitor.guarded_step()
+                                if self.monitor is not None
+                                else self._plain_step())
+            if on:
+                sp.set_metadata(tick=self._tick, active=int(active.sum()),
+                                sampling=int(sampling.sum()),
+                                prompt_rows=self.sched.prompt_rows,
+                                compiles=trace.compiles() - c0)
 
-    def export_observability(self, metrics_json=None, metrics_prom=None,
-                             trace_out=None) -> None:
-        """Write metrics (JSON and/or Prometheus text) and the Chrome
-        trace. Folds the backend's link telemetry into the registry as
-        ``repro_link_*`` counters first, so snapshots are self-contained."""
+    def export_observability(self, metrics_json=None,
+                             metrics_prom=None) -> None:
+        """Write metrics as JSON and/or Prometheus text. Folds the
+        backend's link telemetry into the registry as ``repro_link_*``
+        counters first, so snapshots are self-contained."""
         for k, v in self.backend.link_stats().items():
             c = self.metrics.counter(f"repro_link_{k}_total",
                                      "queue telemetry (LinkStats)")
@@ -167,8 +190,6 @@ class ServeEngine:
             self.metrics.dump_json(metrics_json)
         if metrics_prom:
             self.metrics.dump_prometheus(metrics_prom)
-        if trace_out:
-            self.tracer.dump(trace_out)
 
     def run(self, max_ticks: int = 10_000) -> int:
         """Drive until all submitted requests complete. Returns #ticks.
@@ -178,18 +199,10 @@ class ServeEngine:
         :class:`TicksExhaustedError` is raised — a stuck engine must never
         silently drop requests as if they had been served."""
         ticks = 0
-        t0 = time.perf_counter()
-        tok0 = self.metrics.counter("repro_tokens_total").value
         while self.sched.busy and ticks < max_ticks:
             self._admit()
             self.step()
             ticks += 1
-        elapsed = time.perf_counter() - t0
-        done_toks = self.metrics.counter("repro_tokens_total").value - tok0
-        self.metrics.gauge(
-            "repro_tokens_per_second",
-            "committed tokens / wall time of the last run()").set(
-            done_toks / elapsed if elapsed > 0 else 0.0)
         if self.sched.busy:
             failed = self.sched.fail_all(f"max_ticks={max_ticks} exhausted")
             raise TicksExhaustedError(

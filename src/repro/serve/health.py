@@ -39,6 +39,7 @@ import numpy as np
 import jax
 
 from repro.core import guard
+from repro.obs import trace
 from repro.serve.sharded_cache import DecodeBackend, RingShardedBackend
 
 MODE_LADDER = ("qlr", "xqueue", "sw", "baseline", "dense")
@@ -94,8 +95,7 @@ class HealthMonitor:
     def _note(self, kind: str, detail: str) -> None:
         self.events.append(
             HealthEvent(self.tick, kind, detail, self.eng.backend.name))
-        self.eng.tracer.instant(kind, cat="serve",
-                                args={"tick": self.tick, "detail": detail})
+        trace.instant(f"serve.{kind}", tick=self.tick, detail=detail)
         self.eng.metrics.counter(f"repro_health_{kind}_total",
                                  f"health events of kind {kind}").inc()
 
@@ -120,7 +120,6 @@ class HealthMonitor:
         self._note("degrade", f"{old.name} -> {new.name}")
         eng.metrics.counter("repro_degradations_total",
                             "mode-ladder rungs stepped down").inc()
-        new.tracer = eng.tracer
         eng.backend = new
         self._sync_rung_gauge()
         return True
@@ -140,17 +139,21 @@ class HealthMonitor:
         raise FatalFaultError(why, failed)
 
     # -------------------------------------------------------------- guard
-    def guarded_step(self) -> None:
+    def guarded_step(self):
+        """One guarded tick; returns the (active, sampling) rows of its
+        last plan."""
         eng, hcfg = self.eng, self.hcfg
         self.tick += 1
         snap_sched = eng.sched.snapshot()
         snap_cache = eng.backend.snapshot_cache()
 
         for _ in range(hcfg.max_retries + 1):
-            tokens, active, sampling = eng.sched.plan()
+            with trace.span("serve.plan"):
+                tokens, active, sampling = eng.sched.plan()
             t0 = time.perf_counter()
-            with eng.tracer.span("decode", cat="serve"):
+            with trace.span("serve.step.dispatch"):
                 logits = eng.backend.step(tokens, active)
+            with trace.span("serve.device_wait"):
                 jax.block_until_ready(logits)
             elapsed = time.perf_counter() - t0
 
@@ -165,8 +168,7 @@ class HealthMonitor:
                        else f"step took {elapsed:.3f}s > "
                             f"deadline {hcfg.deadline_s:.3f}s")
                 self._note("link_fault" if link_bad else "deadline", why)
-                eng.tracer.instant("rollback", cat="serve",
-                                   args={"tick": self.tick, "why": why})
+                trace.instant("serve.rollback", tick=self.tick, detail=why)
                 eng.metrics.counter("repro_rollbacks_total",
                                     "ticks rolled back and retried").inc()
                 eng.sched.restore(snap_sched)
@@ -191,9 +193,9 @@ class HealthMonitor:
                     self._note("nonfinite",
                                f"evicted rid={req.rid} slot={int(slot)}")
                     eng.backend.free_slot(int(slot))
-                return
+                return active, sampling
 
             eng._sample_and_commit(logits, sampling)
-            return
+            return active, sampling
 
         self._fatal(f"fault persisted through {hcfg.max_retries} retries")

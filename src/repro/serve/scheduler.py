@@ -34,6 +34,7 @@ and keeps the request-lifecycle counters/gauges current:
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -59,6 +60,7 @@ class Request:
     truncated: bool = False               # max_new clipped by the seq budget
     status: str = STATUS_QUEUED
     finish_reason: str = ""               # length | eos | error | failed
+    t_submit: float = 0.0                 # perf_counter at submit()
 
 
 class Scheduler:
@@ -78,6 +80,7 @@ class Scheduler:
         self.slot_req: list[Optional[Request]] = [None] * max_batch
         self.slot_prompt_left = np.zeros(max_batch, np.int64)
         self.slot_new_left = np.zeros(max_batch, np.int64)
+        self.prompt_rows = 0              # rows the last plan fed a prompt
 
     def _sync_gauges(self) -> None:
         self.metrics.gauge(
@@ -104,7 +107,8 @@ class Scheduler:
         budget = self.max_seq - len(prompt)
         truncated = max_new_tokens > budget
         req = Request(self._next_rid, prompt,
-                      min(max_new_tokens, budget), truncated=truncated)
+                      min(max_new_tokens, budget), truncated=truncated,
+                      t_submit=time.perf_counter())
         self._next_rid += 1
         self.pending.append(req)
         self.metrics.counter("repro_requests_submitted_total",
@@ -156,17 +160,20 @@ class Scheduler:
     def plan(self):
         """Plan one tick: (tokens [B,1] int32, active [B], sampling [B]).
 
-        Slots still consuming their prompt feed the next prompt token;
-        slots whose prompt is exhausted feed their last sampled token and
-        sample again from the step's logits."""
+        Slots still consuming their prompt feed the next prompt token (the
+        count of them is kept as ``prompt_rows``); slots whose prompt is
+        exhausted feed their last sampled token and sample again from the
+        step's logits."""
         tokens = np.zeros((self.max_batch, 1), np.int32)
         active = np.zeros(self.max_batch, bool)
         sampling = np.zeros(self.max_batch, bool)
+        self.prompt_rows = 0
         for slot, req in enumerate(self.slot_req):
             if req is None:
                 continue
             active[slot] = True
             if self.slot_prompt_left[slot] > 0:
+                self.prompt_rows += 1
                 idx = len(req.prompt) - self.slot_prompt_left[slot]
                 tokens[slot, 0] = req.prompt[idx]
                 self.slot_prompt_left[slot] -= 1

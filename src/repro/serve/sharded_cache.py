@@ -49,6 +49,7 @@ retrace; ``link_stats()`` returns the accumulated queue-traffic totals.
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import replace
 
 import numpy as np
@@ -60,7 +61,7 @@ from jax import shard_map
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.core import faults, queues, topology
-from repro.obs import linkstats
+from repro.obs import linkstats, trace
 from repro.core.topology import ring
 from repro.models import build_model
 from repro.models.common import use_sharding
@@ -71,10 +72,16 @@ from repro.sharding.partitioning import (
 )
 
 
-def jit_donating_cache(fn, cache_argnum: int = 1):
+def jit_donating_cache(fn, name: str, cache_argnum: int = 1):
     """``jax.jit(fn)`` with the cache argument donated, so the program
-    updates the cache in place."""
-    return jax.jit(fn, donate_argnums=cache_argnum)
+    updates the cache in place, and named ``jit_<name>`` whatever ``fn``
+    is (a bound method, a closure): the device trace finds the program by
+    that name."""
+    def program(*args):
+        return fn(*args)
+    functools.update_wrapper(program, fn)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, donate_argnums=cache_argnum)
 
 
 class DecodeBackend:
@@ -84,8 +91,6 @@ class DecodeBackend:
     name = "dense"
 
     def __init__(self, cfg: ModelConfig, scfg: ServeConfig, params):
-        from repro.obs.trace import NullTracer
-        self.tracer = NullTracer()        # engine swaps in its own
         self.cfg = cfg
         self.scfg = scfg
         self.model = build_model(cfg)
@@ -93,9 +98,11 @@ class DecodeBackend:
         self.max_seq = scfg.max_seq_len
         self.params = self._place_params(params)
         self.cache = self._init_cache()
-        self._step = jit_donating_cache(self._make_step())
-        self._zero = jit_donating_cache(self._make_zero_row(), 0)
-        self._prefill = jit_donating_cache(self._make_prefill()) \
+        # the program names the device trace keys on
+        self._step = jit_donating_cache(self._make_step(), "decode_step")
+        self._zero = jit_donating_cache(self._make_zero_row(), "zero_row", 0)
+        self._prefill = jit_donating_cache(
+            self._make_prefill(), "prefill_into_cache") \
             if self.supports_prefill else None
 
     # ---------------------------------------------------------- placement
@@ -295,7 +302,7 @@ class RingShardedBackend(DecodeBackend):
         else:
             logits, self.cache = out
         if self.checked:
-            with self.tracer.span("probe", cat="serve"):
+            with trace.span("serve.probe"):
                 self.last_health = self._probe_links(vec)
         return logits
 

@@ -13,15 +13,19 @@ Printed as one JSON line (see tests/test_multidev.py):
    machinery at all, XLA inserts the gathers) records nothing;
 3. toggle — ``set_telemetry(False)`` freezes the totals without a
    rebuild, and re-enabling resumes accumulation (zero retrace);
-4. engine — a monitored ``ServeEngine`` run folds the totals into the
-   metrics registry as ``repro_link_*`` counters and exports a valid
-   snapshot + Chrome trace.
+4. engine — ``launch/serve.py`` serving a monitored ring engine with
+   telemetry folds the totals into the metrics registry as
+   ``repro_link_*`` counters, and ``--trace-out`` records a profiler
+   trace that holds the engine's ``serve.*`` spans.
 """
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
+import glob
 import json
+import shutil
+import tempfile
 
 import numpy as np
 
@@ -32,10 +36,7 @@ from repro.configs import ServeConfig, get_smoke_config
 from repro.core.ring_attention import systolic_ring_decode
 from repro.launch.mesh import make_mesh
 from repro.obs import linkstats
-from repro.obs.trace import Tracer
 from repro.models import build_model, split_tree
-from repro.serve.engine import ServeEngine
-from repro.serve.health import HealthConfig
 from repro.serve.sharded_cache import RingShardedBackend
 
 results = {}
@@ -129,28 +130,33 @@ record("toggle_resumes",
        f"{after_one['pushes']} -> {resumed['pushes']}")
 
 # --- 4. engine integration + exports ---------------------------------------
-backend = fresh("qlr", telemetry=True)
-eng = ServeEngine(cfg, scfg, params, backend=backend,
-                  health=HealthConfig(), tracer=Tracer())
-rng = np.random.default_rng(0)
-for _ in range(scfg.max_batch):
-    eng.submit(rng.integers(1, cfg.vocab_size, size=4).astype(np.int32),
-               max_new_tokens=3)
-eng.run()
+from repro.launch import serve as launch_serve  # noqa: E402
 
-mpath, tpath = "/tmp/check_obs_metrics.json", "/tmp/check_obs_trace.json"
-eng.export_observability(metrics_json=mpath, trace_out=tpath)
+out = tempfile.mkdtemp(prefix="check_obs_")
+mpath, tdir = f"{out}/metrics.json", f"{out}/trace"
+launch_serve.main(["--backend", "ring", "--mesh", "2x4", "--mode", "qlr",
+                   "--monitor", "--telemetry", "--requests", "8",
+                   "--max-new", "3", "--max-batch", "8", "--max-seq", "64",
+                   "--metrics-out", mpath, "--trace-out", tdir])
 snap = json.load(open(mpath))
 record("engine_link_counters",
        snap["counters"].get("repro_link_pushes_total", 0) > 0
        and snap["counters"].get("repro_ticks_total", 0) > 0,
        str({k: v for k, v in snap["counters"].items()
             if k.startswith("repro_link")}))
-trace = json.load(open(tpath))
-names = {e["name"] for e in trace["traceEvents"]}
+xplane = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)
+names = set()
+if xplane:
+    from jax.profiler import ProfileData
+    names = {e.name for plane in ProfileData.from_file(xplane[0]).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")}
 record("engine_trace_spans",
-       {"tick", "decode", "sample"} <= names
-       and all("ts" in e and "ph" in e for e in trace["traceEvents"]),
+       {"serve.admit", "serve.admit_request", "serve.tick", "serve.plan",
+        "serve.step.dispatch", "serve.sample.dispatch", "serve.device_wait",
+        "serve.commit"} <= names,
        str(sorted(names)))
+shutil.rmtree(out, ignore_errors=True)
 
 print(json.dumps(results))

@@ -1,7 +1,7 @@
-"""Observability layer tests (DESIGN.md §8): metrics registry semantics,
-Chrome-trace golden export, link telemetry accounting, the no-retrace
-enable toggle, fault visibility in the error totals, and the utilization
-model's mode ordering.
+"""Observability layer tests (DESIGN.md §8): metrics registry semantics
+and export, link telemetry accounting, the no-retrace enable toggle,
+fault visibility in the error totals, and the utilization model's mode
+ordering (the engine's spans: tests/test_engine_spans.py).
 
 Single-device tier-1: the topology axis is realized as a vmap axis (the
 test_faults.py pattern) and the shard_map republish is emulated with the
@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from repro.core import faults, queues
 from repro.core.topology import ring
 from repro.obs import linkstats, metrics, utilization
-from repro.obs.trace import NullTracer, Tracer
 
 N = 4
 N_STEPS = 4
@@ -71,23 +70,6 @@ def test_histogram_timer():
     assert h.count == 1 and h.sum >= 0.0
 
 
-def test_registry_merge():
-    a, b = metrics.Registry(), metrics.Registry()
-    a.counter("ticks").inc(2)
-    b.counter("ticks").inc(3)
-    b.counter("only_b").inc(1)
-    a.gauge("depth").set(1.0)
-    b.gauge("depth").set(9.0)
-    a.histogram("lat").observe(1.0)
-    b.histogram("lat").observe(3.0)
-    a.merge(b)
-    assert a.counter("ticks").value == 5             # counters add
-    assert a.counter("only_b").value == 1
-    assert a.gauge("depth").value == 9.0             # gauges take theirs
-    assert a.histogram("lat").count == 2             # histograms pool
-    assert a.histogram("lat").quantile(0.5) == pytest.approx(2.0)
-
-
 def test_json_and_prometheus_export(tmp_path):
     reg = metrics.Registry()
     reg.counter("repro_ticks_total", "engine ticks").inc(5)
@@ -113,42 +95,6 @@ def test_json_and_prometheus_export(tmp_path):
     assert "# TYPE repro_tick_latency_seconds summary" in prom
     assert 'repro_tick_latency_seconds{quantile="0.5"}' in prom
     assert "repro_tick_latency_seconds_count 3" in prom
-
-
-# --- trace: golden Chrome trace-event export --------------------------------
-def test_chrome_trace_golden(tmp_path):
-    clock = iter([0.0, 1.0, 1.25, 2.0, 3.5, 4.0]).__next__
-    tr = Tracer(clock=clock, pid=7, tid=3, device_annotations=False)
-    with tr.span("tick", cat="serve", args={"tick": 1}):   # t=1.0 .. 1.25
-        pass
-    tr.instant("rollback", cat="serve", args={"why": "probe"})   # t=2.0
-    with tr.span("decode", cat="serve"):                   # t=3.5 .. 4.0
-        pass
-
-    golden = {
-        "traceEvents": [
-            {"name": "tick", "cat": "serve", "ph": "X", "pid": 7, "tid": 3,
-             "ts": 1_000_000.0, "dur": 250_000.0, "args": {"tick": 1}},
-            {"name": "rollback", "cat": "serve", "ph": "i", "pid": 7,
-             "tid": 3, "ts": 2_000_000.0, "s": "t",
-             "args": {"why": "probe"}},
-            {"name": "decode", "cat": "serve", "ph": "X", "pid": 7, "tid": 3,
-             "ts": 3_500_000.0, "dur": 500_000.0},
-        ],
-        "displayTimeUnit": "ms",
-    }
-    assert tr.to_chrome() == golden
-
-    out = tmp_path / "trace.json"
-    tr.dump(out)
-    assert json.loads(out.read_text()) == golden
-
-
-def test_null_tracer_is_inert():
-    tr = NullTracer()
-    with tr.span("x"):
-        tr.instant("y")
-    assert tr.to_chrome()["traceEvents"] == []
 
 
 # --- linkstats: counting, gating, scan/shard republish ----------------------

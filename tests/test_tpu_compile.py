@@ -150,7 +150,7 @@ def test_qwen3_decode_step_fits_one_chip(one_chip):
                                   sharding=one_chip)
     active = jax.ShapeDtypeStruct((scfg.max_batch,), jnp.bool_,
                                   sharding=one_chip)
-    step = jit_donating_cache(model.decode_step)
+    step = jit_donating_cache(model.decode_step, "decode_step")
     mem = step.lower(*args, tokens, active).compile().memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

@@ -298,29 +298,55 @@ GQA_CACHE_AXES = {
 }
 
 
-def gqa_decode(params, x, cache, cfg: ModelConfig, active=None):
+def _layer(leaf, layer):
+    """One layer of a decode-cache leaf: the leaf itself when ``layer`` is
+    None, else slice ``layer`` of a stacked [L, ...] leaf (read only, so
+    the slice fuses into its consumer instead of being copied out)."""
+    return leaf if layer is None else leaf[layer]
+
+
+def _write_rows(leaf, layer, rows, slots, new):
+    """Write one new entry per row into a decode-cache leaf at (row, slot),
+    in place; a slot past the end drops the write (inactive rows).
+    ``layer`` indexes a stacked [L, ...] leaf; None means one layer's."""
+    idx = (rows, slots) if layer is None else (layer, rows, slots)
+    return leaf.at[idx].set(new, mode="drop")
+
+
+def _write_pos(leaf, layer, new_pos):
+    return new_pos if layer is None else leaf.at[layer].set(new_pos)
+
+
+def gqa_decode(params, x, cache, cfg: ModelConfig, active=None, layer=None):
     """One-token decode. x: [B,1,D]; per-row positions; rows with
     active=False neither write the cache nor advance (continuous batching).
+
+    ``layer`` None: ``cache`` is one layer's. Otherwise ``cache`` is the
+    stacked [L, ...] cache of a layer scan: this step's K/V rows are written
+    into it at (layer, row, slot) and attention reads layer ``layer`` where
+    it lies, so no layer's cache is copied out or back.
 
     When ``cfg.systolic_mode`` is a link mode and the mesh/shapes admit it
     (``ring_decode_applicable``), the attention core runs the decode dual
     of the ring schedule: the cache shards stay resident along the 'model'
     ring and each row's query streams around them with carried
     online-softmax state. Returns (y [B,1,D], new cache)."""
-    pos = cache["pos"]                                       # [B]
+    pos = _layer(cache["pos"], layer)                        # [B]
     b = x.shape[0]
     cfg = _tuned(cfg, "decode", x.shape)
     q, k, v = _qkv(params, x, cfg, pos[:, None].astype(jnp.int32))
-    s_cache = cache["k"].shape[1]
+    s_cache = cache["k"].shape[-3]
     write_idx = jnp.mod(pos, s_cache) if cfg.sliding_window else \
         jnp.minimum(pos, s_cache - 1)
     if active is not None:
         write_idx = jnp.where(active, write_idx, s_cache)    # OOB -> dropped
     rows = jnp.arange(b)
-    k_all = cache["k"].at[rows, write_idx].set(k[:, 0], mode="drop")
-    v_all = cache["v"].at[rows, write_idx].set(v[:, 0], mode="drop")
-    k_all = shard(k_all, "cache_batch", "cache_seq", "kv_heads", "head_dim")
-    v_all = shard(v_all, "cache_batch", "cache_seq", "kv_heads", "head_dim")
+    k_store = _write_rows(cache["k"], layer, rows, write_idx, k[:, 0])
+    v_store = _write_rows(cache["v"], layer, rows, write_idx, v[:, 0])
+    k_all = shard(_layer(k_store, layer),
+                  "cache_batch", "cache_seq", "kv_heads", "head_dim")
+    v_all = shard(_layer(v_store, layer),
+                  "cache_batch", "cache_seq", "kv_heads", "head_dim")
 
     out = None
     ctx = _systolic_attn_ctx(cfg)
@@ -361,7 +387,8 @@ def gqa_decode(params, x, cache, cfg: ModelConfig, active=None):
     out = out.astype(adtype(cfg))
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(adtype(cfg)))
     new_pos = pos + 1 if active is None else pos + active.astype(jnp.int32)
-    new_cache = {"k": k_all, "v": v_all, "pos": new_pos}
+    new_cache = {"k": k_store, "v": v_store,
+                 "pos": _write_pos(cache["pos"], layer, new_pos)}
     return shard(y, "batch", None, "embed"), new_cache
 
 
@@ -495,12 +522,13 @@ MLA_CACHE_AXES = {
 }
 
 
-def mla_decode(params, x, cache, cfg: ModelConfig, active=None):
-    """Absorbed-matrix MLA decode: attention in the latent space."""
+def mla_decode(params, x, cache, cfg: ModelConfig, active=None, layer=None):
+    """Absorbed-matrix MLA decode: attention in the latent space.
+    ``cache`` and ``layer`` as in :func:`gqa_decode`."""
     dt = adtype(cfg)
-    pos = cache["pos"]                                        # [B]
+    pos = _layer(cache["pos"], layer)                         # [B]
     b = x.shape[0]
-    s_cache = cache["c"].shape[1]
+    s_cache = cache["c"].shape[-2]
     positions = pos[:, None].astype(jnp.int32)
     q_nope, q_rope = _mla_queries(params, x, cfg, positions)   # [B,1,H,*]
     c_new, kr_new = _mla_latent(params, x, cfg, positions)     # [B,1,r],[B,1,dr]
@@ -508,10 +536,11 @@ def mla_decode(params, x, cache, cfg: ModelConfig, active=None):
     if active is not None:
         write_idx = jnp.where(active, write_idx, s_cache)
     rows = jnp.arange(b)
-    c_all = cache["c"].at[rows, write_idx].set(c_new[:, 0], mode="drop")
-    kr_all = cache["k_rope"].at[rows, write_idx].set(kr_new[:, 0], mode="drop")
-    c_all = shard(c_all, "cache_batch", "cache_seq", None)
-    kr_all = shard(kr_all, "cache_batch", "cache_seq", None)
+    c_store = _write_rows(cache["c"], layer, rows, write_idx, c_new[:, 0])
+    kr_store = _write_rows(cache["k_rope"], layer, rows, write_idx,
+                           kr_new[:, 0])
+    c_all = shard(_layer(c_store, layer), "cache_batch", "cache_seq", None)
+    kr_all = shard(_layer(kr_store, layer), "cache_batch", "cache_seq", None)
 
     scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
     # absorb: q_lat[b,h,r] = q_nope . W_uk
@@ -527,7 +556,8 @@ def mla_decode(params, x, cache, cfg: ModelConfig, active=None):
     out = jnp.einsum("bshr,rhk->bshk", ctx_lat, params["w_uv"].astype(jnp.float32))
     y = jnp.einsum("bshk,hkd->bsd", out.astype(dt), params["wo"].astype(dt))
     new_pos = pos + 1 if active is None else pos + active.astype(jnp.int32)
-    new_cache = {"c": c_all, "k_rope": kr_all, "pos": new_pos}
+    new_cache = {"c": c_store, "k_rope": kr_store,
+                 "pos": _write_pos(cache["pos"], layer, new_pos)}
     return shard(y, "batch", None, "embed"), new_cache
 
 

@@ -125,13 +125,13 @@ def block_prefill(lp, x, cfg: ModelConfig, moe_layer: bool = False):
     return shard_residual(x + y, cfg), (k, v)
 
 
-def block_decode(lp, x, cache, cfg: ModelConfig, moe_layer: bool = False,
-                 active=None):
+def block_decode(lp, x, cache, layer, cfg: ModelConfig,
+                 moe_layer: bool = False, active=None):
+    """One decoder block on the stacked cache: the attention sublayer
+    writes this step's entries of layer ``layer`` in place."""
     h = apply_norm(lp["norm1"], x, cfg)
-    if cfg.attention_type == "mla":
-        a, cache = attn.mla_decode(lp["attn"], h, cache, cfg, active=active)
-    else:
-        a, cache = attn.gqa_decode(lp["attn"], h, cache, cfg, active=active)
+    decode = attn.mla_decode if cfg.attention_type == "mla" else attn.gqa_decode
+    a, cache = decode(lp["attn"], h, cache, cfg, active=active, layer=layer)
     x = x + a
     h = apply_norm(lp["norm2"], x, cfg)
     if moe_layer:
@@ -142,20 +142,18 @@ def block_decode(lp, x, cache, cfg: ModelConfig, moe_layer: bool = False,
 
 
 def _scan_decode(layer_fn, x, params, cache):
-    """``layer_fn(lp, x, c) -> (x, c)`` over the stacked layers, with the
-    stacked cache in the loop carry: each layer's slice is read and written
-    back in place. (A scan with the cache as xs/ys would stack a second
-    cache, which at serving sizes does not fit next to the first.)"""
+    """``layer_fn(lp, x, cache, i) -> (x, cache)`` over the stacked layers.
+    The whole stacked cache rides in the loop carry; layer ``i`` writes
+    only its new rows into it, at index ``i``, and reads its K/V where they
+    lie, so no layer's cache is sliced out or written back. (A scan with
+    the cache as xs/ys would stack a second cache, which at serving sizes
+    does not fit next to the first.)"""
     n = jax.tree_util.tree_leaves(cache)[0].shape[0]
 
     def body(carry, inp):
         x, cache = carry
         lp, i = inp
-        x, c = layer_fn(lp, x, jax.tree_util.tree_map(lambda a: a[i], cache))
-        cache = jax.tree_util.tree_map(
-            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
-            cache, c)
-        return (x, cache), None
+        return layer_fn(lp, x, cache, i), None
 
     (x, cache), _ = linkstats.scan(body, (x, cache), (params, jnp.arange(n)))
     return x, cache
@@ -345,12 +343,14 @@ class TransformerLM:
 
         if cfg.first_k_dense:
             x, new_cache["dense_layers"] = _scan_decode(
-                lambda lp, x, c: block_decode(lp, x, c, cfg, moe_layer=False,
-                                              active=active),
+                lambda lp, x, c, i: block_decode(lp, x, c, i, cfg,
+                                                 moe_layer=False,
+                                                 active=active),
                 x, params["dense_layers"], cache["dense_layers"])
         x, new_cache["layers"] = _scan_decode(
-            lambda lp, x, c: block_decode(lp, x, c, cfg, moe_layer=self.moe,
-                                          active=active),
+            lambda lp, x, c, i: block_decode(lp, x, c, i, cfg,
+                                             moe_layer=self.moe,
+                                             active=active),
             x, params["layers"], cache["layers"])
 
         x = apply_norm(params["final_norm"], x, cfg)

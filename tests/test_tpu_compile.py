@@ -1,5 +1,5 @@
 """Ahead-of-time compiles for a described TPU v5e: the Pallas kernels at real
-widths and the full-width qwen3-0.6b decode step at the default
+widths and the full-width qwen3-0.6b and olmo-1b decode steps at the default
 ``ServeConfig``. Nothing runs; the TPU compiler either accepts the program
 or raises what the chip would raise. Every kernel case compiles with
 ``interpret=False`` and checks for ``tpu_custom_call``, so an interpret-mode
@@ -11,6 +11,7 @@ cannot be described, every test here skips.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,11 +133,10 @@ def test_fft_stage_compiles(one_chip, stage):
     _assert_kernel(c)
 
 
-def test_qwen3_decode_step_fits_one_chip(one_chip):
+def _compile_decode_step(arch, sharding):
     """The full-width decode step at the default ServeConfig, compiled the
-    way the serving backends jit it (cache donated), fits in one v5e's
-    16 GiB with margin: without the donation the step holds two caches."""
-    cfg = get_config("qwen3-0.6b")
+    way the serving backends jit it (cache donated)."""
+    cfg = get_config(arch)
     scfg = ServeConfig()
     model = build_model(cfg)
     params = jax.eval_shape(
@@ -144,15 +144,81 @@ def test_qwen3_decode_step_fits_one_chip(one_chip):
     cache = jax.eval_shape(
         functools.partial(model.init_cache, scfg.max_batch, scfg.max_seq_len))
     args = [jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         t) for t in (params, cache)]
     tokens = jax.ShapeDtypeStruct((scfg.max_batch, 1), jnp.int32,
-                                  sharding=one_chip)
+                                  sharding=sharding)
     active = jax.ShapeDtypeStruct((scfg.max_batch,), jnp.bool_,
-                                  sharding=one_chip)
+                                  sharding=sharding)
     step = jit_donating_cache(model.decode_step, "decode_step")
-    mem = step.lower(*args, tokens, active).compile().memory_analysis()
+    return step.lower(*args, tokens, active).compile(), cache
+
+
+def test_qwen3_decode_step_fits_one_chip(one_chip):
+    """The full-width decode step at the default ServeConfig, compiled the
+    way the serving backends jit it (cache donated), fits in one v5e's
+    16 GiB with margin: without the donation the step holds two caches."""
+    compiled, _ = _compile_decode_step("qwen3-0.6b", one_chip)
+    mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.alias_size_in_bytes > 6 * GIB, mem      # the cache is donated
     assert total < 15 * GIB, mem
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)")
+
+
+def _layer_copies(hlo: str, dtype: str, slab: tuple) -> list[str]:
+    """Instructions of optimized HLO that copy one layer's cache slab
+    (``dtype[*slab]``, leading 1s aside) out of the stacked cache or back
+    into it: a slab-shaped value written to memory (produced outside any
+    fusion, or the result of a fusion) or a dynamic-update-slice whose
+    update is a slab. A slice fused into the op that reads it is no copy."""
+    comps: dict[str, list] = {}
+    body: list = []
+    for line in hlo.splitlines():
+        if (m := _COMPUTATION.match(line)):
+            body = comps.setdefault(m.group(1), [])
+        elif (m := _INSTRUCTION.match(line)):
+            dims = tuple(int(d) for d in m.group(4).split(",") if d)
+            body.append((bool(m.group(1)), m.group(2), m.group(3), dims,
+                         m.group(5), re.findall(r"%([\w.\-]+)", m.group(6)),
+                         line.strip()))
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    shape = {i[1]: (i[2], i[3]) for b in comps.values() for i in b}
+
+    def is_slab(dt, dims):
+        while dims[:1] == (1,):
+            dims = dims[1:]
+        return dt == dtype and dims == tuple(slab)
+
+    copies = []
+    for name, instrs in comps.items():
+        for root, _, dt, dims, op, operands, line in instrs:
+            if op == "dynamic-update-slice":
+                if is_slab(*shape.get(operands[1], ("", ()))):
+                    copies.append(line)
+            elif is_slab(dt, dims) and (
+                    root if name in fused
+                    else op not in ("parameter", "get-tuple-element")):
+                copies.append(line)
+    return copies
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmo-1b"])
+def test_decode_step_writes_cache_in_place(one_chip, arch):
+    """Each layer's new K/V rows go straight into the stacked cache and
+    attention reads the layer where it lies: no layer's [B,S,Kv,hd] slab
+    is copied out of the stack or written back. olmo-1b (MHA, nothing to
+    widen) then needs almost no temporaries."""
+    compiled, cache = _compile_decode_step(arch, one_chip)
+    k = cache["layers"]["k"]
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[k.dtype.name]
+    copies = _layer_copies(compiled.as_text(), dtype, k.shape[1:])
+    assert not copies, "\n".join(c[:200] for c in copies)
+    if arch == "olmo-1b":
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 64 * 2 ** 20, temp

@@ -2,10 +2,9 @@
 read from, and the device time per call of one jitted program."""
 from __future__ import annotations
 
-import importlib.util
 from dataclasses import dataclass, field
 
-from bench.spec import BENCH
+from bench import plugins
 
 
 @dataclass
@@ -35,9 +34,4 @@ def idle_percent(ctx: Context) -> float | None:
 
 def read(name: str, ctx: Context):
     """Run ``metrics/<name>.py``'s ``read``; None where it finds nothing."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return plugins.load("metrics", name).read(ctx)

@@ -1,12 +1,12 @@
-"""Plain float32 reference of the dense transformer family.
+"""Plain float32 reference, and what it is compared by.
 
-Written from the published description (pre-norm decoder, RMSNorm or
-non-parametric LayerNorm, optional RMS qk-norm, rotary embeddings with the
-rotate-half layout, grouped-query attention, SwiGLU, tied embeddings) and
-importing nothing of the program. Every matrix product runs at
-``Precision.HIGHEST`` in float32, layer by layer, with attention in blocks
-of queries and logits in blocks of rows, so that it fits next to nothing
-else on the chip.
+Each family's forward (``hidden``, ``head_matrix`` in
+``families/<family>.py``) is written from the published description and
+imports nothing of the program; what is here is the same for every family:
+the products, the served tokens' gaps, the loss and the AdamW step. Every
+matrix product runs at ``Precision.HIGHEST`` in float32, layer by layer,
+with attention in blocks of queries and logits in blocks of rows, so that
+it fits next to nothing else on the chip.
 
 ``fp8=True`` is the control: the same arithmetic with the two operands of
 every product rounded to float8 e4m3 (one scale per weight matrix, one per
@@ -14,11 +14,12 @@ activation row), the step below the bfloat16 the configurations state.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from bench import plugins
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0                # largest finite float8 e4m3fn
@@ -50,93 +51,15 @@ def mm(eq: str, a, b, fp8: bool = False, a_axes=-1, b_axes=None):
                       preferred_element_type=jnp.float32)
 
 
-def norm(x, scale, kind: str, eps: float):
-    if kind == "rmsnorm":
-        y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-        return y * scale.astype(jnp.float32)
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps)      # nonparametric
-
-
-def rope(x, pos, theta: float):
-    """Rotate-half rotary embedding. x [n, L, heads, hd], pos [L]."""
-    hd = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = pos.astype(jnp.float32)[:, None] * inv[None]        # [L, hd/2]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(q, k, v, fp8: bool):
-    """Causal softmax attention over query blocks. q [n,L,H,hd], k/v
-    [n,L,Kv,hd] -> [n,L,H,hd]."""
-    n, L, H, hd = q.shape
-    rep = H // k.shape[2]
-    k = jnp.repeat(k, rep, axis=2)
-    v = jnp.repeat(v, rep, axis=2)
-    qb = min(Q_BLOCK, L)
-    if L % qb:
-        raise ValueError(f"{L} positions are not a whole number of "
-                         f"{qb}-query blocks")
-    qs = q.reshape(n, L // qb, qb, H, hd).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def block(args):
-        qi, i = args
-        s = mm("nqhd,nkhd->nhqk", qi, k, fp8, -1, -1) / math.sqrt(hd)
-        qpos = i * qb + jnp.arange(qb)
-        mask = jnp.arange(L)[None, :] <= qpos[:, None]
-        s = jnp.where(mask[None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return mm("nhqk,nkhd->nqhd", p, v, fp8, -1, 1)
-
-    out = jax.lax.map(block, (qs, jnp.arange(L // qb)))
-    return out.swapaxes(0, 1).reshape(n, L, H, hd)
-
-
-def layer(conf: dict, fp8: bool, x, lp):
-    kind, eps = conf["norm"], _eps(conf)
-    pos = jnp.arange(x.shape[1])
-    h = norm(x, lp.get("norm1", {}).get("scale"), kind, eps)
-    a = lp["attn"]
-    q = mm("nld,dhk->nlhk", h, a["wq"], fp8)
-    k = mm("nld,dhk->nlhk", h, a["wk"], fp8)
-    v = mm("nld,dhk->nlhk", h, a["wv"], fp8)
-    if conf["qk_norm"]:
-        q = norm(q, a["q_norm"], "rmsnorm", _eps(conf))
-        k = norm(k, a["k_norm"], "rmsnorm", _eps(conf))
-    q = rope(q, pos, float(conf["rope_theta"]))
-    k = rope(k, pos, float(conf["rope_theta"]))
-    o = attention(q, k, v, fp8)
-    x = x + mm("nlhk,hkd->nld", o, a["wo"], fp8, (-2, -1))
-    h = norm(x, lp.get("norm2", {}).get("scale"), kind, eps)
-    m = lp["mlp"]
-    g = mm("nld,df->nlf", h, m["w_gate"], fp8)
-    u = mm("nld,df->nlf", h, m["w_up"], fp8)
-    return x + mm("nlf,fd->nld", jax.nn.silu(g) * u, m["w_down"], fp8)
-
-
-def _eps(conf: dict) -> float:
-    return float(conf.get("rms_norm_eps",
-                          conf.get("assumed", {}).get("layer_norm_eps", 1e-5)))
-
-
 def hidden(conf: dict, w, tokens, fp8: bool = False):
-    """Final-normed hidden states [n, L, d] for tokens [n, L]."""
-    x = jnp.take(w["embed"]["table"].astype(LOOKUP_DTYPE), tokens,
-                 axis=0).astype(jnp.float32)
-    body = jax.checkpoint(partial(layer, conf, fp8))
-    x, _ = jax.lax.scan(lambda x, lp: (body(x, lp), None), x, w["layers"])
-    return norm(x, w["final_norm"].get("scale"), conf["norm"], _eps(conf))
+    """Final-normed hidden states [n, L, d] for tokens [n, L], by the
+    configuration's family (``families/<family>.py``)."""
+    return plugins.family(conf).hidden(conf, w, tokens, fp8)
 
 
 def head_matrix(conf: dict, w):
     """[V, d] rows whose dot with a hidden state is the logit."""
-    if conf["tie_word_embeddings"]:
-        return w["embed"]["table"]
-    return w["head"]["w"].T
+    return plugins.family(conf).head_matrix(conf, w)
 
 
 def _row_blocks(x, block):

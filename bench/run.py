@@ -13,7 +13,8 @@ reports the cell's end-to-end metrics, ``--trace 1`` its per-layer ones,
 read from a profiler trace of the window.
 
 Exits non-zero, printing no result, where JAX finds no TPU or fewer chips
-than the cell asks for, or where the program under ``src/`` is missing.
+than the cell asks for, where the program under ``src/`` is missing, or
+where the configuration's family or backend has no file under ``bench/``.
 """
 from __future__ import annotations
 
@@ -68,13 +69,11 @@ def serve(cell, args, devices):
     """A serving run: (end-to-end metrics, records for the per-layer
     readers, numbers compared, counts and device)."""
     from bench import serving as sd
-    from bench import tracing
-    engine, win = sd.setup(cell, args.seed)
+    engine, win = sd.setup(cell, args.seed, devices)
     setup_s = time.perf_counter() - T_START
     tdir = _trace_start(args)
     length = win.run(args.seconds)
-    if tdir:
-        tracing.stop()
+    _trace_stop(tdir)
     e2e = {"setup_s": setup_s, **sd.end_to_end(win)}
     late = sorted(win.lateness)
     log(f"set-up {setup_s!r} s; window {length!r} s: {len(win.sent)} "
@@ -107,14 +106,12 @@ def serve(cell, args, devices):
 def train(cell, args, devices):
     """A training run, returning what ``serve`` does."""
     from bench import training as td
-    from bench import tracing
     tr = td.Trainer(cell, args.seed)
     prog = tr.checked_steps()
     setup_s = time.perf_counter() - T_START
     tdir = _trace_start(args)
     steps, length = tr.run(args.seconds)
-    if tdir:
-        tracing.stop()
+    _trace_stop(tdir)
     mix = cell.traffic
     e2e = {"setup_s": setup_s,
            "train_tok_s": steps * mix["batch"] * mix["seq_len"] / length}
@@ -145,12 +142,27 @@ def _trace_start(args):
     return tdir
 
 
+def _trace_stop(tdir) -> None:
+    if not tdir:
+        return
+    from bench import tracing
+    t0 = time.perf_counter()
+    tracing.stop()
+    log(f"profiler stopped in {time.perf_counter() - t0!r} s")
+
+
 def _trace_reduce(tdir, spans):
     if not tdir:
         return None
     from bench import tracing
     try:
-        return tracing.reduce(tracing.find_xplane(tdir), spans)
+        t0 = time.perf_counter()
+        tr = tracing.read(tracing.find_xplane(tdir))
+        t1 = time.perf_counter()
+        out = tracing.reduce(tr, spans)
+        log(f"trace read in {t1 - t0!r} s, reduced in "
+            f"{time.perf_counter() - t1!r} s")
+        return out
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
 
@@ -201,8 +213,13 @@ def main(argv=None, *, cell=None, require_chip: bool = True) -> int:
     for p in (str(ROOT / "src"), str(ROOT)):
         if p not in sys.path:
             sys.path.insert(0, p)
-    from bench import spec
+    from bench import plugins, spec
     cell = cell or spec.load_cell(args.workload)
+    try:
+        plugins.check(cell.config)
+    except FileNotFoundError as e:
+        log(str(e))
+        return 2
     import jax
     devices = jax.devices()
     chips = cell.workload["chips"]

@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from bench import reference, traffic, weights
+from bench import plugins, reference, traffic, weights
 from bench.spec import model_config, serve_config
 from bench.tracing import WINDOW
 
@@ -28,14 +28,10 @@ def p95(values) -> float:
     return float(np.percentile(np.asarray(values, np.float64), 95))
 
 
-def build_backend(cfg, scfg, params, conf):
-    """The backend the configuration names; ``"dense"`` is the program's
-    ``DecodeBackend`` on one chip, the only one a cell uses so far."""
-    kind = conf["serve"]["backend"]
-    if kind != "dense":
-        raise ValueError(f"unknown serving backend {kind!r}")
-    from repro.serve.sharded_cache import DecodeBackend
-    return DecodeBackend(cfg, scfg, params)
+def build_backend(cfg, scfg, params, conf, devices):
+    """The backend the configuration names (``serve.backend``), built by
+    ``backends/<backend>.py`` over the run's devices."""
+    return plugins.backend(conf).build(cfg, scfg, params, conf, devices)
 
 
 class Window:
@@ -189,19 +185,22 @@ def sample_finished(win: Window, seed: int, want_tokens: int) -> list:
     return picked
 
 
-def setup(cell, seed: int):
+def setup(cell, seed: int, devices=None):
     """Weights, engine, cache, warm-up, and the part of the schedule
-    before the window; returns (engine, window)."""
+    before the window, on ``devices`` (the first chips the cell asks for
+    by default); returns (engine, window)."""
     import jax
     from repro.models import build_model
     from repro.serve.engine import ServeEngine
     conf, mix = cell.config, cell.traffic
+    if devices is None:
+        devices = jax.devices()[:cell.workload["chips"]]
     cfg, scfg = model_config(conf), serve_config(conf)
     weights.check_layout(conf, build_model(cfg))
-    params = weights.make_weights(conf, seed)
-    engine = ServeEngine(cfg, scfg, params,
-                         backend=build_backend(cfg, scfg, params, conf))
-    del params
+    backend = build_backend(cfg, scfg, weights.make_weights(conf, seed),
+                            conf, devices)
+    # the engine keeps the backend's placed weights, not a second copy
+    engine = ServeEngine(cfg, scfg, backend.params, backend=backend)
     warm = traffic.rng_for(seed, 5).integers(0, cfg.vocab_size, 20)
     engine.submit(warm.astype(np.int32), max_new_tokens=2)
     while engine.sched.busy:

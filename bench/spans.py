@@ -27,15 +27,6 @@ TICK_FAMILY = (TICK, "serve.plan", "serve.step.dispatch", "serve.probe",
 NONE = "none"
 
 
-def _load(path: str):
-    from jax.profiler import ProfileData
-    if path.endswith(".gz"):
-        import gzip
-        with gzip.open(path, "rb") as f:
-            return ProfileData.from_serialized_xspace(f.read())
-    return ProfileData.from_file(path)
-
-
 def innermost(spans, points) -> list:
     """For each of the sorted ``points``, the name of the innermost of
     ``spans`` ((start, end, name), nested as one thread's spans are) open
@@ -54,45 +45,31 @@ def innermost(spans, points) -> list:
     return out
 
 
-def reduce(path: str) -> dict:
+def reduce(path, merged=None) -> dict:
     """The window's program spans and the device idle time by span (see
-    the module doc). Times in seconds from the window's start."""
-    pd = _load(path)
-    host, devices = [], []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:") and "/" not in \
-                plane.name[len("/device:TPU:"):]:
-            ops = [ln for ln in plane.lines if ln.name == "XLA Ops"]
-            if ops:
-                devices.append([(e.start_ns, e.start_ns + e.duration_ns)
-                                for e in ops[0].events])
-        elif plane.name.startswith("/host:"):
-            for ln in plane.lines:
-                host += [(e.name, e.start_ns, e.start_ns + e.duration_ns, e)
-                         for e in ln.events
-                         if e.name == tracing.WINDOW or
-                         e.name.startswith(PREFIX)]
-    win = [ev for ev in host if ev[0] == tracing.WINDOW]
-    if not win or not devices:
-        raise ValueError(f"trace has {len(win)} window spans and "
-                         f"{len(devices)} TPU planes with XLA Ops")
-    lo, hi = win[0][1], win[0][2]
+    the module doc). Times in seconds from the window's start. ``path`` is
+    a path or a ``tracing.Trace``; ``merged`` is each chip's union of
+    operation intervals in the window, where it has been taken already."""
+    tr = path if isinstance(path, tracing.Trace) else tracing.read(path)
+    lo, hi = tr.lo, tr.hi
+    if merged is None:
+        merged = [tracing.union(tracing._clip([ev[1:] for ev in ops], lo, hi))
+                  for ops, _ in tr.devices]
     ns = 1e-9
-    prog = [ev for ev in host if ev[0].startswith(PREFIX)]
+    prog = [ev for ev in tr.host if ev[0].startswith(PREFIX)]
     spans = sorted(([name, (s - lo) * ns, (e - lo) * ns, dict(ev.stats)]
                     for name, s, e, ev in prog if lo <= s < hi),
                    key=lambda sp: (sp[1], -sp[2]))
     open_ = [(s, e, name) for name, s, e, _ in prog if e > lo and s < hi]
     idle: dict = {}
-    for intervals in devices:
-        merged = tracing.union(tracing._clip(intervals, lo, hi))
-        edges = [lo] + [x for iv in merged for x in iv] + [hi]
+    for chip in merged:
+        edges = [lo] + [x for iv in chip for x in iv] + [hi]
         gaps = [(gs, ge) for gs, ge in zip(edges[::2], edges[1::2])
                 if ge > gs]
         who = innermost(open_, [(gs + ge) / 2 for gs, ge in gaps])
         for (gs, ge), name in zip(gaps, who):
             idle[name or NONE] = idle.get(name or NONE, 0) + ge - gs
-    n = len(devices)
+    n = len(merged)
     return {"chips": n, "window_s": (hi - lo) * ns, "spans": spans,
             "idle": {k: v * ns / n for k, v in
                      sorted(idle.items(), key=lambda kv: -kv[1])}}

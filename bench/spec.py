@@ -3,7 +3,9 @@
 A workload names a configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``); its limits for ``correct`` are in
 ``cells/<workload>.json``, and each per-layer metric is read by
-``metrics/<metric>.py``. Nothing here knows a cell by name.
+``metrics/<metric>.py``; a configuration's model family and serving
+backend are files too (``plugins.py``). Nothing here knows a cell by
+name.
 """
 from __future__ import annotations
 
@@ -65,25 +67,10 @@ def load_cell(name: str, bench_json: Path = ROOT / "BENCHMARK.json") -> Cell:
 
 # ------------------------------------------------ configs for the program
 def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file (dense family,
-    Hugging Face key names)."""
-    from repro.configs.base import ModelConfig
-    norm = conf["norm"]
-    eps = conf.get("rms_norm_eps", conf.get("assumed", {}).get(
-        "layer_norm_eps", 1e-5))
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or
-        conf["hidden_size"] // conf["num_attention_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        norm_type=norm, norm_eps=float(eps), qk_norm=conf["qk_norm"],
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=conf["tie_word_embeddings"], mlp_kind="swiglu",
-        use_attn_bias=conf["attention_bias"],
-        dtype=conf["serve_dtype"], param_dtype=conf["serve_dtype"])
+    """The program's ``ModelConfig`` for a configuration file, by its
+    family (``families/<family>.py``)."""
+    from bench import plugins
+    return plugins.family(conf).model_config(conf)
 
 
 def serve_config(conf: dict):

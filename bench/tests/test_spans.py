@@ -98,3 +98,16 @@ def test_recorded_window_numbers(recorded):
                                                              rel=1e-4)
     assert spans.decode_prompt_row_share(recorded) == pytest.approx(100 / 3)
     assert spans.window_compiles(recorded) == 0
+
+
+@pytest.mark.parametrize("name", sorted(spans.METRICS))
+def test_readers_take_the_spans_from_the_trace_reduction(name, recorded):
+    """Each reader ``metrics/<name>.py`` reads, from ``tracing.reduce``'s
+    ``program_spans``, the number ``bench/spans.py`` gives."""
+    from bench import tracing
+    from bench.layer import Context, read
+    red = tracing.reduce(TRACE)
+    assert red["program_spans"] == recorded
+    ctx = Context({}, {}, "TPU v5 lite", 1, red)
+    assert read(name, ctx) == spans.METRICS[name](recorded)
+    assert read(name, Context({}, {}, "TPU v5 lite", 1, None)) is None

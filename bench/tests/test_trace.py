@@ -59,3 +59,46 @@ def test_union_and_self_time_helpers():
     ev = [("loop", 0, 10), ("a", 1, 3), ("b", 4, 9), ("c", 5, 6)]
     assert tracing.self_times(ev) == {"loop": 3, "a": 2, "b": 4, "c": 1}
     assert tracing.program_name("jit_decode_step(123)") == "decode_step"
+
+
+@pytest.mark.parametrize("text,name,coll", [
+    ("%collective-permute-start.1 = (bf16[8]{0}, bf16[8]{0}, u32[], u32[]) "
+     "collective-permute-start(bf16[8]{0} %x), source_target_pairs={{0,1}}",
+     "collective-permute-start.1", True),
+    ("%cp = bf16[8]{0:T(512)} collective-permute(bf16[8]{0} %x)", "cp", True),
+    ("%all-gather-fusion.2 = bf16[8]{0} fusion(bf16[2]{0} %p), kind=kLoop, "
+     "calls=%fused_computation.2", "all-gather-fusion.2", True),
+    # a fusion that takes a permuted operand is compute, not a collective
+    ("%fusion.3 = bf16[8]{0:T(512)} fusion(bf16[8]{0} "
+     "%collective-permute-done.1), kind=kLoop, calls=%fused_computation.3",
+     "fusion.3", False),
+    ("%and_reduce_fusion = pred[]{:T(512)} fusion(s32[1]{0:T(128)} %bitcast),"
+     " kind=kLoop, calls=%fused_computation.5", "and_reduce_fusion", False),
+])
+def test_op_name_and_collective_class(text, name, coll):
+    assert tracing.op_info(text) == (name, coll)
+
+
+def test_shortest_open_span_matches_a_scan():
+    import random
+    rng = random.Random(7)
+    spans = []
+    for k in range(60):
+        s = rng.randrange(0, 1000)
+        spans.append((f"s{k % 7}", s, s + rng.randrange(1, 200)))
+    points = sorted(rng.uniform(-10, 1300) for _ in range(400))
+
+    def scan(t):
+        open_ = [sp for sp in spans if sp[1] <= t < sp[2]]
+        return min(open_, key=lambda sp: sp[2] - sp[1])[0] if open_ \
+            else None
+    assert tracing.shortest_open(spans, points) == [scan(t) for t in points]
+
+
+def test_one_read_feeds_both_reductions():
+    """A trace read once gives what reading it from its path gives, the
+    program's spans with it."""
+    from bench import spans
+    tr = tracing.read(TRACE)
+    assert tracing.reduce(tr, ("tick",)) == tracing.reduce(TRACE, ("tick",))
+    assert tracing.reduce(tr)["program_spans"] == spans.reduce(TRACE)

@@ -1,5 +1,6 @@
 """Work counts and peaks against figures worked out by hand at the
-published widths of qwen3-0.6b and OLMo-1B."""
+published widths of qwen3-0.6b and OLMo-1B; the counts' parts are the
+dense family's (``families/dense.py``)."""
 import json
 import sys
 from pathlib import Path
@@ -9,7 +10,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT)]
 
-from bench import peaks, work  # noqa: E402
+from bench import peaks, plugins, work  # noqa: E402
 
 
 def conf(name):
@@ -18,23 +19,24 @@ def conf(name):
 
 
 QWEN, OLMO = conf("qwen3-0.6b"), conf("olmo-1b")
+dense = plugins.family(QWEN)
 
 
 def test_parameter_counts():
     # qwen3: 1024*(16+2*8)*128 + 16*128*1024 + 3*1024*3072 per layer
-    assert work.layer_matmul_params(QWEN) == 15_728_640
-    assert work.matmul_params(QWEN) == 28 * 15_728_640 + 151_936 * 1024
+    assert dense.layer_matmul_params(QWEN) == 15_728_640
+    assert dense.matmul_params(QWEN) == 28 * 15_728_640 + 151_936 * 1024
     # + 57 RMSNorm scales of 1024, 56 qk-norm scales of 128, tied table
-    assert work.weight_bytes(QWEN) == 2 * (440_401_920 + 58_368 + 7_168
+    assert dense.weight_bytes(QWEN) == 2 * (440_401_920 + 58_368 + 7_168
                                            + 155_582_464)
     # olmo: 2048*48*128 + 16*128*2048 + 3*2048*8192, no norm weights
-    assert work.layer_matmul_params(OLMO) == 67_108_864
-    assert work.weight_bytes(OLMO) == 2 * (16 * 67_108_864 + 50_304 * 2048)
+    assert dense.layer_matmul_params(OLMO) == 67_108_864
+    assert dense.weight_bytes(OLMO) == 2 * (16 * 67_108_864 + 50_304 * 2048)
 
 
 def test_kv_bytes_per_token():
-    assert work.kv_bytes_per_token(QWEN) == 114_688
-    assert work.kv_bytes_per_token(OLMO) == 131_072
+    assert dense.kv_bytes_per_token(QWEN) == 114_688
+    assert dense.kv_bytes_per_token(OLMO) == 131_072
 
 
 def test_decode_step():
@@ -51,7 +53,7 @@ def test_prefill_counts_the_prompt_once():
     layers = 16 * 67_108_864
     attn = 4 * 16 * 16 * 128 * 256 * 257 / 2
     assert f == pytest.approx(2 * layers * 256 + attn + 2 * 50_304 * 2048)
-    assert b == work.weight_bytes(OLMO) + 131_072 * 256
+    assert b == dense.weight_bytes(OLMO) + 131_072 * 256
 
 
 def test_train_step_qwen_4x4096():

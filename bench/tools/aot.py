@@ -33,18 +33,22 @@ def on(dev, tree):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), tree)
 
 
-def report(name, compiled):
+def report(name, compiled) -> dict:
+    """Print a program's memory analysis; returns its bytes."""
     m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    print(f"{name}: args {m.argument_size_in_bytes / GiB:.3f} GiB, out "
-          f"{m.output_size_in_bytes / GiB:.3f}, temp "
-          f"{m.temp_size_in_bytes / GiB:.3f}, alias "
-          f"{m.alias_size_in_bytes / GiB:.3f}: {total / GiB:.3f} GiB in all",
+    out = {"argument": m.argument_size_in_bytes,
+           "output": m.output_size_in_bytes, "temp": m.temp_size_in_bytes,
+           "alias": m.alias_size_in_bytes}
+    total = out["argument"] + out["output"] + out["temp"] - out["alias"]
+    print(f"{name}: args {out['argument'] / GiB:.3f} GiB, out "
+          f"{out['output'] / GiB:.3f}, temp {out['temp'] / GiB:.3f}, alias "
+          f"{out['alias'] / GiB:.3f}: {total / GiB:.3f} GiB in all",
           flush=True)
+    return out
 
 
-def serve_programs(cell, dev):
+def serve_programs(cell, dev) -> dict:
+    """{program: bytes} of a serving cell's programs on one device."""
     from repro.models import build_model
     conf = cell.config
     cfg, scfg = spec.model_config(conf), spec.serve_config(conf)
@@ -55,11 +59,12 @@ def serve_programs(cell, dev):
     i32 = jnp.int32
     tok = on(dev, jax.ShapeDtypeStruct((scfg.max_batch, 1), i32))
     act = on(dev, jax.ShapeDtypeStruct((scfg.max_batch,), jnp.bool_))
-    report("decode_step", jax.jit(model.decode_step, donate_argnums=1).lower(
-        p, cache, tok, act).compile())
+    out = {"decode_step": report("decode_step", jax.jit(
+        model.decode_step, donate_argnums=1).lower(
+        p, cache, tok, act).compile())}
     chunk = on(dev, jax.ShapeDtypeStruct((scfg.prefill_chunk,), i32))
     s = on(dev, jax.ShapeDtypeStruct((), i32))
-    report("prefill_into_cache", jax.jit(
+    out["prefill_into_cache"] = report("prefill_into_cache", jax.jit(
         model.prefill_into_cache, donate_argnums=1).lower(
         p, cache, chunk, s, s).compile())
     # the reference over the checked requests: at most this many rows of
@@ -67,8 +72,11 @@ def serve_programs(cell, dev):
     rows = 16
     L = scfg.max_seq_len
     toks = on(dev, jax.ShapeDtypeStruct((rows, L), i32))
-    report(f"reference hidden [{rows} x {L}]", jax.jit(
-        lambda w, t: reference.hidden(conf, w, t)).lower(p, toks).compile())
+    out["reference_hidden"] = report(
+        f"reference hidden [{rows} x {L}]", jax.jit(
+            lambda w, t: reference.hidden(conf, w, t)).lower(
+            p, toks).compile())
+    return out
 
 
 def train_programs(cell, dev):

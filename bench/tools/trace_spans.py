@@ -59,7 +59,7 @@ def window(cell, seed: int, seconds: float, keep: str) -> dict:
             out[name] = None if s is None else 1e3 * s
         out.update(busy_s=red["busy_s"], trace_window_s=red["window_s"],
                    programs=red["programs"], idle_gaps=red["idle_gaps"])
-        prog = spans.reduce(path)
+        prog = red["program_spans"]
         out.update({k: f(prog) for k, f in spans.METRICS.items()})
         out["idle_by_span"] = prog["idle"]
         if keep:

@@ -1,5 +1,6 @@
-"""Random weights of the dense family, made from the seed on the device in
-one jitted call, in the program's parameter layout and serving dtype.
+"""Random weights, made from the seed on the device in one jitted call, in
+the program's parameter layout (the family's ``layout`` and ``skeleton``)
+and serving dtype.
 
 Matrices are normal with fan-in scaling; norm scales are 1 + N(0, 0.1^2),
 so a norm whose scale were dropped would show; the (tied) embedding has a
@@ -10,10 +11,10 @@ own.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
+
+from bench import plugins
 
 
 def seed_key(seed: int):
@@ -23,36 +24,12 @@ def seed_key(seed: int):
                               (seed >> 31) & 0x7FFFFFFF)
 
 
-def layout(conf: dict) -> dict:
-    """{path: (shape, std or 'norm')} in the program's parameter tree."""
-    d, f = conf["hidden_size"], conf["intermediate_size"]
-    h, kv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = conf.get("head_dim") or d // h
-    n, v = conf["num_hidden_layers"], conf["vocab_size"]
-    out = {("embed", "table"): ((v, d), 2.0 / math.sqrt(d)),
-           ("layers", "attn", "wq"): ((n, d, h, hd), 1 / math.sqrt(d)),
-           ("layers", "attn", "wk"): ((n, d, kv, hd), 1 / math.sqrt(d)),
-           ("layers", "attn", "wv"): ((n, d, kv, hd), 1 / math.sqrt(d)),
-           ("layers", "attn", "wo"): ((n, h, hd, d), 1 / math.sqrt(h * hd)),
-           ("layers", "mlp", "w_gate"): ((n, d, f), 1 / math.sqrt(d)),
-           ("layers", "mlp", "w_up"): ((n, d, f), 1 / math.sqrt(d)),
-           ("layers", "mlp", "w_down"): ((n, f, d), 1 / math.sqrt(f))}
-    if conf["qk_norm"]:
-        out[("layers", "attn", "q_norm")] = ((n, hd), "norm")
-        out[("layers", "attn", "k_norm")] = ((n, hd), "norm")
-    if conf["norm"] == "rmsnorm":
-        out[("layers", "norm1", "scale")] = ((n, d), "norm")
-        out[("layers", "norm2", "scale")] = ((n, d), "norm")
-        out[("final_norm", "scale")] = ((d,), "norm")
-    if not conf["tie_word_embeddings"]:
-        out[("head", "w")] = ((d, v), 1 / math.sqrt(d))
-    return out
-
-
 def _tree(conf: dict, leaf) -> dict:
-    tree: dict = {"embed": {}, "final_norm": {}, "head": {},
-                  "layers": {"attn": {}, "mlp": {}, "norm1": {}, "norm2": {}}}
-    for i, (path, spec) in enumerate(sorted(layout(conf).items())):
+    """The family's parameter tree, each leaf ``leaf(i, shape, std)`` with
+    ``i`` its rank in the sorted layout."""
+    fam = plugins.family(conf)
+    tree = fam.skeleton(conf)
+    for i, (path, spec) in enumerate(sorted(fam.layout(conf).items())):
         node = tree
         for p in path[:-1]:
             node = node[p]
